@@ -122,12 +122,13 @@ class DeltaTracker:
         """Gather one encoding group's changed rows off the device. The
         gather width pads to the next power of two (capped at the chunk
         count) so fluctuating change counts compile O(log G) gather variants
-        per leaf instead of one per novel count. Returns
-        {enc, idx, bytes, <wire arrays per encoding>}."""
+        per leaf instead of one per novel count. The index vector stays a
+        host array, so it lands on the leaf's own device, not the default
+        one. Returns {enc, idx, bytes, <wire arrays per encoding>}."""
         c = int(idx.size)
         cap = min(1 << (c - 1).bit_length(), n_real)
-        idx_pad = jnp.asarray(np.concatenate(
-            [idx, np.full(cap - c, idx[0], idx.dtype)]), jnp.int32)
+        idx_pad = np.concatenate(
+            [idx, np.full(cap - c, idx[0], idx.dtype)]).astype(np.int32)
         if enc == "q8":
             q, s = gather_quantize_blocks(h["leaf"], idx_pad,
                                           self.chunk_words)

@@ -295,10 +295,7 @@ def _resharded_leaf(store, resolved: dict, leaf: dict, sharding,
 def _target_sharding(x):
     """``x``'s NamedSharding if it has one (the selective-restore trigger);
     None for host arrays / single-device jax arrays."""
-    try:
-        from jax.sharding import NamedSharding
-    except ImportError:                                    # pragma: no cover
-        return None
+    from jax.sharding import NamedSharding
     sh = getattr(x, "sharding", None)
     return sh if isinstance(sh, NamedSharding) else None
 

@@ -45,15 +45,13 @@ def quantize_pallas(x: jnp.ndarray, *, interpret: bool = True,
     )(x)
 
 
-def _gather_quant_kernel(idx_ref, x_ref, q_ref, scale_ref, *, block: int):
+def _gather_quant_kernel(idx_ref, x_ref, q_ref, scale_ref):
     del idx_ref  # consumed by the BlockSpec index_map, not the body
-    x = x_ref[...].astype(jnp.float32)               # [1, W] selected row
-    W = x.shape[-1]
-    sub = x.reshape(W // block, block)
-    scale = jnp.maximum(jnp.max(jnp.abs(sub), axis=1) / 127.0, 1e-12)
-    q = jnp.clip(jnp.round(sub / scale[:, None]), -127, 127)
-    q_ref[...] = q.reshape(1, W).astype(jnp.int8)
-    scale_ref[...] = scale.reshape(1, W // block).astype(jnp.float32)
+    sub = x_ref[...].astype(jnp.float32)             # [W // block, block]
+    scale = jnp.maximum(jnp.max(jnp.abs(sub), axis=1, keepdims=True) / 127.0,
+                        1e-12)
+    q_ref[...] = jnp.clip(jnp.round(sub / scale), -127, 127).astype(jnp.int8)
+    scale_ref[...] = scale
 
 
 def gather_quantize_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
@@ -63,8 +61,9 @@ def gather_quantize_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
     ``x`` is the [G, W] float chunk view of a leaf, ``idx`` the int32 [C]
     changed-row indices. The grid runs one program per changed row; the row
     index is scalar-prefetched so the BlockSpec index_map DMAs only the
-    selected rows into VMEM — frozen rows are never read. Each row is
-    quantized per ``block``-element sub-block (same codec layout as
+    selected rows into VMEM — frozen rows are never read. Each row arrives
+    as a squeezed [W // block, block] tile (one sub-block per sublane) and
+    is quantized per sub-block (same codec layout as
     parallel/compression.py). Returns (q int8 [C, W], scales f32
     [C, W // block])."""
     G, W = x.shape
@@ -74,34 +73,40 @@ def gather_quantize_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(C,),
-        in_specs=[pl.BlockSpec((1, W), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=[pl.BlockSpec((1, W), lambda i, idx_ref: (i, 0)),
-                   pl.BlockSpec((1, n_sub), lambda i, idx_ref: (i, 0))],
+        in_specs=[pl.BlockSpec((None, n_sub, block),
+                               lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=[pl.BlockSpec((None, n_sub, block),
+                                lambda i, idx_ref: (i, 0, 0)),
+                   pl.BlockSpec((None, n_sub, 1),
+                                lambda i, idx_ref: (i, 0, 0))],
     )
-    return pl.pallas_call(
-        functools.partial(_gather_quant_kernel, block=block),
+    q, scale = pl.pallas_call(
+        _gather_quant_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((C, W), jnp.int8),
-                   jax.ShapeDtypeStruct((C, n_sub), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((C, n_sub, block), jnp.int8),
+                   jax.ShapeDtypeStruct((C, n_sub, 1), jnp.float32)],
         interpret=interpret,
-    )(idx, x)
+    )(idx, x.reshape(G, n_sub, block))
+    return q.reshape(C, W), scale.reshape(C, n_sub)
 
 
-def _gather_quant4_kernel(idx_ref, x_ref, p_ref, scale_ref, *, block: int):
+def _gather_quant4_kernel(idx_ref, x_ref, p_ref, scale_ref, *, shared: bool):
     del idx_ref  # consumed by the BlockSpec index_map, not the body
-    x = x_ref[...].astype(jnp.float32)               # [1, W] selected row
-    W = x.shape[-1]
-    sub = x.reshape(W // block, block)
-    scale = jnp.maximum(jnp.max(jnp.abs(sub), axis=1) / 7.0, 1e-12)
-    q = jnp.clip(jnp.round(sub / scale[:, None]), -7, 7).astype(jnp.int32)
-    q = q.reshape(1, W)
+    lo = x_ref[0].astype(jnp.float32)                # [m, L] first half
+    hi = x_ref[1].astype(jnp.float32)                # [m, L] second half
+    a_lo = jnp.max(jnp.abs(lo), axis=1, keepdims=True)
+    a_hi = jnp.max(jnp.abs(hi), axis=1, keepdims=True)
+    if shared:                                       # one block spans both
+        a_lo = a_hi = jnp.maximum(a_lo, a_hi)
+    s_lo = jnp.maximum(a_lo / 7.0, 1e-12)
+    s_hi = jnp.maximum(a_hi / 7.0, 1e-12)
+    q_lo = jnp.clip(jnp.round(lo / s_lo), -7, 7).astype(jnp.int32) & 0xF
+    q_hi = jnp.clip(jnp.round(hi / s_hi), -7, 7).astype(jnp.int32) & 0xF
     # half-split nibble pack: low nibble = elements [0, W/2), high nibble =
-    # [W/2, W) — contiguous lane slices instead of a stride-2 shuffle, which
-    # is what the TPU vector unit can actually do cheaply
-    lo = q[:, : W // 2] & 0xF
-    hi = q[:, W // 2:] & 0xF
-    p_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
-    scale_ref[...] = scale.reshape(1, W // block).astype(jnp.float32)
+    # [W/2, W) — the two halves are separate tiles, so no lane shuffle
+    p_ref[...] = (q_lo | (q_hi << 4)).astype(jnp.uint8)
+    scale_ref[0] = s_lo
+    scale_ref[1] = s_hi
 
 
 def gather_quantize4_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
@@ -111,26 +116,35 @@ def gather_quantize4_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
     Same scalar-prefetch gather shape as :func:`gather_quantize_pallas`, but
     each row quantizes to signed int4 (clip ±7) and packs two nibbles per
     byte with the half-split layout (element j in the low nibble of byte j,
-    element j + W/2 in its high nibble). Returns (packed uint8 [C, W // 2],
-    scales f32 [C, W // block])."""
+    element j + W/2 in its high nibble). A row arrives as a squeezed
+    [2, m, L] tile: its two halves, each m sub-blocks of L lanes (or, when
+    one block is the whole row, one L = W/2 slice per half sharing a
+    scale). Returns (packed uint8 [C, W // 2], scales f32
+    [C, W // block])."""
     G, W = x.shape
     C = int(idx.shape[0])
-    assert W % block == 0 and W % 2 == 0, (W, block)
     n_sub = W // block
+    shared = n_sub == 1
+    assert W % block == 0 and (shared or n_sub % 2 == 0), (W, block)
+    m, L = (1, W // 2) if shared else (n_sub // 2, block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(C,),
-        in_specs=[pl.BlockSpec((1, W), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=[pl.BlockSpec((1, W // 2), lambda i, idx_ref: (i, 0)),
-                   pl.BlockSpec((1, n_sub), lambda i, idx_ref: (i, 0))],
+        in_specs=[pl.BlockSpec((None, 2, m, L),
+                               lambda i, idx_ref: (idx_ref[i], 0, 0, 0))],
+        out_specs=[pl.BlockSpec((None, m, L),
+                                lambda i, idx_ref: (i, 0, 0)),
+                   pl.BlockSpec((None, 2, m, 1),
+                                lambda i, idx_ref: (i, 0, 0, 0))],
     )
-    return pl.pallas_call(
-        functools.partial(_gather_quant4_kernel, block=block),
+    packed, scale = pl.pallas_call(
+        functools.partial(_gather_quant4_kernel, shared=shared),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((C, W // 2), jnp.uint8),
-                   jax.ShapeDtypeStruct((C, n_sub), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((C, m, L), jnp.uint8),
+                   jax.ShapeDtypeStruct((C, 2, m, 1), jnp.float32)],
         interpret=interpret,
-    )(idx, x)
+    )(idx, x.reshape(G, 2, m, L))
+    return packed.reshape(C, W // 2), scale.reshape(C, 2 * m)[:, :n_sub]
 
 
 def _dequant_kernel(q_ref, scale_ref, x_ref):

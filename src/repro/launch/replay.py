@@ -56,8 +56,10 @@ def worker_main(args):
     import repro.configs as C
     import repro.flor as flor
     from repro.data import synthetic_batch
+    from repro.launch.compile_cache import use_compile_cache
     from repro.train.step import build_train_step
 
+    use_compile_cache()
     cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
     init_state, train_step = build_train_step(cfg)
     ts = jax.jit(train_step)
@@ -90,6 +92,33 @@ def worker_main(args):
                         flor.log("probe_grad_norm", m["grad_norm"])
                 if sess.executed("train"):
                     flor.log("loss", m["loss"])
+
+
+def _probe_backend() -> tuple[str, int]:
+    """(platform, device count) of the default JAX backend, read in a child
+    process so that this one never opens it."""
+    out = subprocess.run(
+        [sys.executable, "-c", "import jax; d = jax.devices(); "
+         "print(d[0].platform, len(d))"],
+        capture_output=True, text=True, check=True, env=os.environ.copy())
+    platform, count = out.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def _check_nworkers(ap, nworkers: int) -> None:
+    """Refuse more concurrent workers than the accelerator admits, before
+    any work starts. Each worker is its own process, and on an accelerator
+    one process opens every chip of the host and holds them until it exits
+    (the TPU runtime admits one process per host), so a second worker would
+    wait on a held chip. CPU workers share the host freely."""
+    if nworkers <= 1 or os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    platform, count = _probe_backend()
+    if platform != "cpu":
+        ap.error(f"--nworkers {nworkers}: each replay worker process holds "
+                 f"the whole {platform} backend ({count} chip(s) visible), "
+                 f"which one process per host can open at a time; use "
+                 f"--nworkers 1 on this host")
 
 
 def _print_store_summary(run_dir: str):
@@ -246,6 +275,7 @@ def main():
     if args.pid is not None:
         worker_main(args)
         return
+    _check_nworkers(ap, args.nworkers)
     if args.no_plan:
         if args.probe == "auto":
             # the legacy fan-out has no planner to consume the detection:

@@ -24,7 +24,24 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+
+def _done_epochs(store) -> set:
+    """Epochs with a complete checkpoint in ``store``. Shard MEMBER
+    manifests (<key>.shard<h>) and checkpoints a distributed record marked
+    incomplete never anchor a resume — only stitched (or flat) epoch keys
+    count as done."""
+    from repro.checkpoint.store import _safe
+    inc = {_safe(k) for k in
+           (store.get_meta("incomplete_ckpts") or {}).get("keys") or ()}
+    done = set()
+    for k in store.list_keys():
+        if "_at_" in k and ".shard" not in k and k not in inc:
+            try:
+                done.add(int(k.split("_at_")[1].split(".")[0]))
+            except ValueError:
+                pass
+    return done
 
 
 def main():
@@ -80,9 +97,14 @@ def main():
 
     import repro.configs as C
     import repro.flor as flor
-    from repro.data import PrefetchLoader, synthetic_batch
+    from repro.data import synthetic_batch
+    from repro.launch.compile_cache import CompileClock, use_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.parallel import use_mesh
     from repro.train.step import build_train_step
+
+    use_compile_cache()
+    clock = CompileClock()
 
     # true multi-process record: join the fleet BEFORE any jax call touches
     # the backend, so jax.devices() spans every host
@@ -100,15 +122,27 @@ def main():
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     if group is not None and mesh is None:
         ap.error("--num-processes > 1 requires --mesh (the global device "
                  "mesh spanning every process)")
     shard_axes = tuple(a for a in args.ckpt_shard_axes.split(",") if a)
 
+    key = jax.random.PRNGKey(args.seed)
     with use_mesh(mesh):
-        ts = jax.jit(train_step)
-        state = jax.jit(init_state)(jax.random.PRNGKey(args.seed))
+        if mesh is None:
+            ts = jax.jit(train_step)
+            state = jax.jit(init_state)(key)
+        else:
+            # the state lives sharded over the mesh and keeps its layout
+            # from step to step, so every checkpoint shards the same way
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from repro.launch.specs import state_shardings
+            st_sh = state_shardings(cfg, mesh,
+                                    jax.eval_shape(init_state, key))
+            ts = jax.jit(train_step,
+                         out_shardings=(st_sh, NamedSharding(mesh, P())))
+            state = jax.jit(init_state, out_shardings=st_sh)(key)
 
         if args.no_flor:
             t0 = time.time()
@@ -121,6 +155,7 @@ def main():
                 jax.block_until_ready(m["loss"])
                 print(f"epoch {epoch} loss {float(m['loss']):.4f}", flush=True)
             print(f"vanilla wall {time.time() - t0:.2f}s")
+            print(clock, flush=True)
             return
 
         with flor.Session(
@@ -129,13 +164,12 @@ def main():
                                        adaptive=not args.no_adaptive,
                                        async_log=not args.sync_log,
                                        log_spill_bytes=args.log_spill_bytes,
-                                       # distributed: sharded checkpoints
-                                       # over the global mesh, per-process
-                                       # local shards, lead-stitched v4s
-                                       mesh=mesh if group is not None
-                                       else None,
-                                       ckpt_shard_axes=shard_axes
-                                       if group is not None else (),
+                                       # with a mesh: each device checkpoints
+                                       # its own shards (v4 manifests);
+                                       # distributed: per-process local
+                                       # shards, lead-stitched v4s
+                                       mesh=mesh,
+                                       ckpt_shard_axes=shard_axes,
                                        distributed=group or False,
                                        stitch_timeout_s=args.stitch_timeout),
                 lineage=flor.LineageSpec(store_root=args.store_root,
@@ -149,21 +183,8 @@ def main():
                 print(f"warm start from run {ctx.parent_run!r}", flush=True)
                 state = sess.warm_start("train", like=state)
                 state = jax.tree_util.tree_map(jnp.asarray, state)
-            # crash-restart: resume from the latest epoch checkpoint if any.
-            # Shard MEMBER manifests (<key>.shard<h>) and checkpoints a
-            # distributed record marked incomplete never anchor a resume —
-            # only stitched (or flat) epoch keys count as done.
-            from repro.checkpoint.store import _safe
-            inc = {_safe(k) for k in
-                   (ctx.store.get_meta("incomplete_ckpts") or {})
-                   .get("keys") or ()}
-            done = set()
-            for k in ctx.store.list_keys():
-                if "_at_" in k and ".shard" not in k and k not in inc:
-                    try:
-                        done.add(int(k.split("_at_")[1].split(".")[0]))
-                    except ValueError:
-                        pass
+            # crash-restart: resume from the latest epoch checkpoint if any
+            done = _done_epochs(ctx.store)
             resume_from = max(done) + 1 if done else 0
             if resume_from:
                 # physical restore of the latest Loop End Checkpoint, then
@@ -184,9 +205,13 @@ def main():
                                             epoch * steps + s, args.seed)
                         ckpt.state, m = ts(ckpt.state, b)
                     flor.log("loss", m["loss"])
-                    print(f"epoch {epoch} done", flush=True)
+                    print(f"epoch {epoch} done loss {float(m['loss']):.6f}",
+                          flush=True)
             state = ckpt.state
         print(f"record wall {time.time() - t0:.2f}s")
+        print("checkpoints: " + ",".join(
+            str(e) for e in sorted(_done_epochs(ctx.store))), flush=True)
+        print(clock, flush=True)
 
 
 if __name__ == "__main__":

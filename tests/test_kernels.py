@@ -5,9 +5,13 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.chunk_delta import changed_mask_pallas, fingerprint_pallas
+from repro.kernels.chunk_delta import (changed_mask_pallas,
+                                       fingerprint_changed_pallas,
+                                       fingerprint_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.quantize import dequantize_pallas, quantize_pallas
+from repro.kernels.quantize import (dequantize_pallas,
+                                    gather_quantize4_pallas,
+                                    gather_quantize_pallas, quantize_pallas)
 from repro.kernels.ops import (
     dequantize_blocks, fingerprint_leaf, quantize_blocks)
 
@@ -19,6 +23,42 @@ def test_fingerprint_matches_ref(g, b):
     x = jax.random.bits(jax.random.PRNGKey(g * b), (g, b), jnp.uint32)
     np.testing.assert_array_equal(np.asarray(fingerprint_pallas(x)),
                                   np.asarray(ref.fingerprint_ref(x)))
+
+
+@pytest.mark.parametrize("g,b", [(8, 128), (16, 1024), (16, 384), (64, 64)])
+def test_fingerprint_changed_matches_ref(g, b):
+    """The fused kernel's digests and (G, 1)-laid mask equal the oracle."""
+    x = jax.random.bits(jax.random.PRNGKey(g + b), (g, b), jnp.uint32)
+    prev = ref.fingerprint_ref(x).at[1, 0].add(1).at[g - 1, 1].add(1)
+    d, mask = fingerprint_changed_pallas(x, prev)
+    d_r, mask_r = ref.fingerprint_changed_ref(x, prev)
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_r))
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(mask_r))
+    assert mask.shape == (g,) and mask.dtype == jnp.int32
+    assert np.flatnonzero(np.asarray(mask)).tolist() == [1, g - 1]
+
+
+@pytest.mark.parametrize("w,block", [(1024, 256), (512, 256), (256, 256)])
+def test_gather_quantize_matches_ref(w, block):
+    """q8 and q4 gathers over squeezed row tiles: scales to float rounding,
+    codes to one level (the kernel and the oracle may divide differently);
+    (256, 256) is the one-block-per-row case q4 splits across halves."""
+    x = jax.random.normal(jax.random.PRNGKey(w), (16, w)) * 3.0
+    idx = jnp.asarray([1, 4, 7, 15], jnp.int32)
+    q, s = gather_quantize_pallas(x, idx, block=block)
+    q_r, s_r = ref.gather_quantize_ref(x, idx, block)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_r), rtol=1e-6)
+    assert np.max(np.abs(np.asarray(q, np.int32)
+                         - np.asarray(q_r, np.int32))) <= 1
+    p, s = gather_quantize4_pallas(x, idx, block=block)
+    p_r, s_r = ref.gather_quantize4_ref(x, idx, block)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_r), rtol=1e-6)
+    for a, b in ((np.asarray(p) & 0xF, np.asarray(p_r) & 0xF),
+                 (np.asarray(p) >> 4, np.asarray(p_r) >> 4)):
+        d = (a.astype(np.int8) - ((a > 7) << 4)) \
+            - (b.astype(np.int8) - ((b > 7) << 4))
+        assert np.max(np.abs(d)) <= 1
+    assert p.shape == (4, w // 2) and s.shape == (4, w // block)
 
 
 def test_fingerprint_detects_single_bit_flip():

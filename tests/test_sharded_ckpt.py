@@ -42,7 +42,7 @@ def test_spec_entries_json_form():
 def test_respec_resolves_and_falls_back():
     import jax
     from jax.sharding import PartitionSpec as P
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # recorded spec re-resolves verbatim when divisible
     assert respec(["data", "model"], (8, 8), mesh) == P("data", "model")
     # non-divisible dim drops the offending axis (replicates)

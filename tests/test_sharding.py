@@ -13,12 +13,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mesh(shape=(2, 2), axes=("data", "model")):
-    # build an ABSTRACT mesh: resolver only needs axis names/sizes.
-    # jax >= 0.4.36 takes ((name, size), ...) pairs; older took (shape, names)
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(shape, axes)
+    # an ABSTRACT mesh: the resolver only needs axis names/sizes
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 from repro.parallel.sharding import physical_spec  # noqa: E402
@@ -102,6 +98,7 @@ def test_small_mesh_train_and_decode_lowering():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         import repro.configs as C
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import (batch_shardings, cache_shardings,
                                         param_shardings, state_shardings)
         from repro.configs.base import ShapeSpec
@@ -112,7 +109,7 @@ def test_small_mesh_train_and_decode_lowering():
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg = C.get_smoke("granite-3-2b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         model = build_model(cfg)
         shape = ShapeSpec("t", "train", 64, 4)
         with mesh, use_mesh(mesh):
