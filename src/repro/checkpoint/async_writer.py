@@ -35,6 +35,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from repro.utils.timing import span
+
 _STOP = object()
 
 
@@ -97,7 +99,8 @@ class AsyncStage:
 
 class AsyncWriter:
     """Checkpoint materialization stage: FIFO jobs ``fn(store)`` executed on
-    the writer thread, per-job wall time reported to ``on_materialized``."""
+    the writer thread inside one ``flor.write`` span each (``ckpt=<key>``),
+    per-job wall time reported to ``on_materialized``."""
 
     def __init__(self, store, max_queue: int = 2,
                  on_materialized: Optional[Callable] = None):
@@ -109,7 +112,8 @@ class AsyncWriter:
     def _run(self, item):
         key, fn = item
         t0 = time.perf_counter()
-        stat = fn(self.store) or {}
+        with span("flor.write", ckpt=key):
+            stat = fn(self.store) or {}
         stat.setdefault("key", key)
         stat["materialize_s"] = time.perf_counter() - t0
         self._stats.append(stat)

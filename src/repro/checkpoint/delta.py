@@ -21,8 +21,9 @@ import numpy as np
 
 from repro.kernels.ops import (CHUNK_WORDS, chunk_absmax,
                                fingerprint_and_changed, fingerprint_leaf,
-                               gather_changed_blocks, gather_quantize4_blocks,
+                               gather_changed_rows, gather_quantize4_blocks,
                                gather_quantize_blocks, native_bytes_per_word)
+from repro.utils.timing import span
 
 # Error-bound encoding selector thresholds. The TRUE per-element bound of a
 # blockwise codec is half a quantization step: absmax/254 for q8 (scale =
@@ -64,7 +65,8 @@ class DeltaTracker:
         self._digests: dict[str, jnp.ndarray] = {}
 
     def delta_dispatch(self, path: str, leaf, *, quantize: bool = False,
-                       enc: str = None, error_bound: float = None) -> dict:
+                       enc: str = None, error_bound: float = None,
+                       ckpt: str = None) -> dict:
         """Phase 1 of a delta: launch the device work (fused fingerprint +
         changed-mask when a previous digest exists) WITHOUT any host sync,
         and update the stored digest to the new device array. Returns an
@@ -88,7 +90,12 @@ class DeltaTracker:
         the caller keeps training. Host numpy leaves are retained by
         REFERENCE: a caller that mutates one in place between dispatch and
         finalize would gather post-mutation bytes (functional updates, the
-        norm here, are unaffected)."""
+        norm here, are unaffected).
+
+        ``ckpt`` (the checkpoint key) rides on this delta's profiler spans —
+        ``flor.ckpt.fingerprint`` here and in finalize, ``flor.ckpt.copy``
+        around each gather — so one checkpoint's spans share it across
+        threads."""
         if enc is None:
             enc = "q8" if quantize else "raw"
         if error_bound is not None:
@@ -99,55 +106,59 @@ class DeltaTracker:
             else np.asarray(leaf).dtype
         bpw = native_bytes_per_word(dtype)
         prev = self._digests.get(path)
-        if prev is not None \
-                and int(prev.shape[0]) == _grid_rows(nbytes, bpw,
-                                                     self.chunk_words):
-            digest, mask = fingerprint_and_changed(leaf, prev,
-                                                   self.chunk_words)
-            first = False
-        else:
-            digest = fingerprint_leaf(leaf, self.chunk_words)
-            mask = None
-            first = True                              # first sight: all new
+        with span("flor.ckpt.fingerprint", ckpt=ckpt):
+            if prev is not None \
+                    and int(prev.shape[0]) == _grid_rows(nbytes, bpw,
+                                                         self.chunk_words):
+                digest, mask = fingerprint_and_changed(leaf, prev,
+                                                       self.chunk_words)
+                first = False
+            else:
+                digest = fingerprint_leaf(leaf, self.chunk_words)
+                mask = None
+                first = True                          # first sight: all new
+            absmax = chunk_absmax(leaf, self.chunk_words) \
+                if enc == "auto" else None
         self._digests[path] = digest
-        absmax = chunk_absmax(leaf, self.chunk_words) if enc == "auto" \
-            else None
         return {"path": path, "leaf": leaf, "digest": digest, "mask": mask,
                 "first": first, "enc": enc, "quantize": (enc == "q8"),
                 "error_bound": error_bound, "absmax": absmax,
-                "nbytes": nbytes, "bpw": bpw}
+                "nbytes": nbytes, "bpw": bpw, "ckpt": ckpt}
 
     def _gather_group(self, h: dict, enc: str, idx: np.ndarray,
-                      n_real: int) -> dict:
-        """Gather one encoding group's changed rows off the device. The
-        gather width pads to the next power of two (capped at the chunk
-        count) so fluctuating change counts compile O(log G) gather variants
-        per leaf instead of one per novel count. The index vector stays a
-        host array, so it lands on the leaf's own device, not the default
-        one. Returns {enc, idx, bytes, <wire arrays per encoding>}."""
+                      n_real: int, counters: dict) -> dict:
+        """Gather one encoding group's changed rows off the device, inside
+        a ``flor.ckpt.copy`` span whose seconds add into
+        ``counters["copy_s"]``. The gather width pads to the next power of
+        two (capped at the chunk count) so fluctuating change counts compile
+        O(log G) gather variants per leaf instead of one per novel count.
+        The index vector stays a host array, so it lands on the leaf's own
+        device, not the default one. Returns {enc, idx, bytes, <wire arrays
+        per encoding>}."""
         c = int(idx.size)
         cap = min(1 << (c - 1).bit_length(), n_real)
         idx_pad = np.concatenate(
             [idx, np.full(cap - c, idx[0], idx.dtype)]).astype(np.int32)
-        if enc == "q8":
-            q, s = gather_quantize_blocks(h["leaf"], idx_pad,
-                                          self.chunk_words)
-            q = np.ascontiguousarray(np.asarray(jax.device_get(q))[:c])
-            s = np.ascontiguousarray(np.asarray(jax.device_get(s))[:c])
-            return {"enc": "q8", "idx": idx, "q": q, "scales": s,
-                    "bytes": int(q.nbytes + s.nbytes)}
-        if enc == "q4":
-            p, s = gather_quantize4_blocks(h["leaf"], idx_pad,
-                                           self.chunk_words)
-            p = np.ascontiguousarray(np.asarray(jax.device_get(p))[:c])
-            s = np.ascontiguousarray(np.asarray(jax.device_get(s))[:c])
-            return {"enc": "q4", "idx": idx, "packed": p, "scales": s,
-                    "bytes": int(p.nbytes + s.nbytes)}
-        rows = np.asarray(jax.device_get(gather_changed_blocks(
-            h["leaf"], idx_pad, self.chunk_words)))
-        rows = np.ascontiguousarray(rows[:c])
-        return {"enc": "raw", "idx": idx, "blocks": rows,
-                "bytes": int(rows.nbytes)}
+        with span("flor.ckpt.copy", counters, "copy_s", ckpt=h.get("ckpt")):
+            if enc == "q8":
+                q, s = gather_quantize_blocks(h["leaf"], idx_pad,
+                                              self.chunk_words)
+                q = np.ascontiguousarray(np.asarray(jax.device_get(q))[:c])
+                s = np.ascontiguousarray(np.asarray(jax.device_get(s))[:c])
+                return {"enc": "q8", "idx": idx, "q": q, "scales": s,
+                        "bytes": int(q.nbytes + s.nbytes)}
+            if enc == "q4":
+                p, s = gather_quantize4_blocks(h["leaf"], idx_pad,
+                                               self.chunk_words)
+                p = np.ascontiguousarray(np.asarray(jax.device_get(p))[:c])
+                s = np.ascontiguousarray(np.asarray(jax.device_get(s))[:c])
+                return {"enc": "q4", "idx": idx, "packed": p, "scales": s,
+                        "bytes": int(p.nbytes + s.nbytes)}
+            rows = np.asarray(jax.device_get(gather_changed_rows(
+                h["leaf"], idx_pad, self.chunk_words)))
+            rows = np.ascontiguousarray(rows[:c])
+            return {"enc": "raw", "idx": idx, "blocks": rows,
+                    "bytes": int(rows.nbytes)}
 
     def finalize(self, h: dict) -> dict:
         """Phase 2: sync the change mask, gather the changed rows in wire
@@ -158,21 +169,24 @@ class DeltaTracker:
 
         Returns {digest, mask (np bool [G]), enc_groups ([{enc, idx, ...}]
         — one group per distinct wire encoding chosen), changed_idx,
-        transferred_bytes, total_bytes} plus the legacy single-encoding
-        fields (changed_blocks for raw handles, changed_q/changed_scales
-        for q8) older callers still read."""
-        digest = h["digest"]
-        g = int(digest.shape[0])
-        if h["first"]:
-            mask = np.ones((g,), bool)
-        else:
-            mask = np.asarray(jax.device_get(h["mask"])).astype(bool)
+        transferred_bytes, copy_s (seconds in the gathers and their
+        device-to-host copies), total_bytes} plus the legacy
+        single-encoding fields (changed_blocks for raw handles,
+        changed_q/changed_scales for q8) older callers still read."""
+        g = int(h["digest"].shape[0])
+        with span("flor.ckpt.fingerprint", ckpt=h.get("ckpt")):
+            if h["first"]:
+                mask = np.ones((g,), bool)
+            else:
+                mask = np.asarray(jax.device_get(h["mask"])).astype(bool)
+            digest = np.asarray(jax.device_get(h["digest"]))
         nbytes, bpw = h["nbytes"], h["bpw"]
         n_real = max(1, -(-nbytes // (self.chunk_words * bpw)))
         idx = np.flatnonzero(mask[:n_real])
         enc = h.get("enc", "q8" if h.get("quantize") else "raw")
         groups: list[dict] = []
         transferred = 0
+        timing = {"copy_s": 0.0}
         if idx.size:
             if enc == "auto":
                 # per-chunk selector: the cheapest encoding whose GUARANTEED
@@ -185,9 +199,11 @@ class DeltaTracker:
                 for e in ("q4", "q8", "raw"):
                     sub = idx[pick == e]
                     if sub.size:
-                        groups.append(self._gather_group(h, e, sub, n_real))
+                        groups.append(self._gather_group(h, e, sub, n_real,
+                                                         timing))
             else:
-                groups.append(self._gather_group(h, enc, idx, n_real))
+                groups.append(self._gather_group(h, enc, idx, n_real,
+                                                 timing))
             transferred = sum(gr["bytes"] for gr in groups)
         # legacy single-encoding view (raw/q8 callers predate enc_groups)
         changed = None
@@ -199,7 +215,7 @@ class DeltaTracker:
             changed_q = groups[0]["q"]
             changed_scales = groups[0]["scales"]
         return {
-            "digest": np.asarray(jax.device_get(digest)),
+            "digest": digest,
             "mask": mask,
             "changed_blocks": changed,
             "changed_q": changed_q,
@@ -207,11 +223,13 @@ class DeltaTracker:
             "enc_groups": groups,
             "changed_idx": idx,
             "transferred_bytes": transferred,
+            "copy_s": timing["copy_s"],
             "total_bytes": int(g * self.chunk_words * 4),
         }
 
     def delta(self, path: str, leaf, *, quantize: bool = False,
-              enc: str = None, error_bound: float = None) -> dict:
+              enc: str = None, error_bound: float = None,
+              ckpt: str = None) -> dict:
         """Synchronous delta: dispatch + finalize in one call (see the two
         phases above). Updates the stored digest — call exactly once per
         MATERIALIZED checkpoint so the mask always means "changed since the
@@ -226,7 +244,8 @@ class DeltaTracker:
         """
         return self.finalize(self.delta_dispatch(path, leaf,
                                                  quantize=quantize, enc=enc,
-                                                 error_bound=error_bound))
+                                                 error_bound=error_bound,
+                                                 ckpt=ckpt))
 
     def seed(self, path: str, leaf):
         """Rehydrate one leaf's device-side digests from restored bytes

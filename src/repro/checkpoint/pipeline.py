@@ -112,6 +112,7 @@ from repro.kernels.ops import (Q4_BLOCK, Q8_BLOCK, native_bytes_per_word,
                                q4_encode_chunk, q8_encode_chunk,
                                quantizable_dtype)
 from repro.parallel.compression import entropy_encode_bytes
+from repro.utils.timing import span
 
 DEFAULT_FULL_EVERY = 8
 # fallback hop cost for full_every="auto" before any replay calibration has
@@ -252,6 +253,7 @@ class CheckpointPipeline:
         payload_leaves = []
         rollback: list[tuple[str, Any]] = []
         transferred = 0
+        copy_s = 0.0
         logical = 0
         changed_chunks_n = 0
         total_chunks_n = 0
@@ -296,16 +298,15 @@ class CheckpointPipeline:
                 # dispatch-only: the fused fingerprint+mask launches here;
                 # mask sync, gather and encode run on the writer thread
                 lmeta["handle"] = self.tracker.delta_dispatch(
-                    tpath, _fp_view(leaf), **dkw)
+                    tpath, _fp_view(leaf), ckpt=key, **dkw)
             else:
-                d = self.tracker.delta(tpath, _fp_view(leaf), **dkw)
-                idx_keep, chunks_keep, encs_keep, t_bytes = _encode_changed(
-                    d, lmeta, self.chunk_words)
-                lmeta["changed_idx"] = idx_keep
-                lmeta["chunks"] = chunks_keep
-                lmeta["chunk_encs"] = encs_keep
+                d = self.tracker.delta(tpath, _fp_view(leaf), ckpt=key,
+                                       **dkw)
+                t_bytes, n_changed = _attach_changed(d, lmeta,
+                                                     self.chunk_words, key)
                 transferred += t_bytes
-                changed_chunks_n += len(idx_keep)
+                changed_chunks_n += n_changed
+                copy_s += d["copy_s"]
             payload_leaves.append(lmeta)
         if set(prev_sig) - set(sig):           # leaf removed
             structure_changed = True
@@ -323,13 +324,15 @@ class CheckpointPipeline:
             # writer thread finalizes the deferred gathers (None here; the
             # materialized stat carries the measured values)
             "transferred_bytes": None if self.overlap else transferred,
+            "copy_s": None if self.overlap else copy_s,
             "logical_bytes": logical,
             "changed_chunks": None if self.overlap else changed_chunks_n,
             "total_chunks": total_chunks_n,
             # foreground stall on the training thread (fused fingerprint +
             # mask sync + changed-row DMA — or dispatch-only in overlap
             # mode): part of the real M_i — the epsilon overhead invariant
-            # is meaningless if this goes uncounted
+            # is meaningless if this goes uncounted. It stops before the
+            # wait on the writer's queue, which is the stat's queue_wait_s
             "submit_stall_s": time.perf_counter() - t_submit0,
         }
         ok = self._dispatch(payload, block=block)
@@ -355,11 +358,26 @@ class CheckpointPipeline:
                 "submit_stall_s": payload["submit_stall_s"]}
 
     def _dispatch(self, payload: dict, block: bool) -> bool:
-        job = self._make_job(payload)
+        # the job fills this dict with the write path's counters
+        # (CheckpointStore.counting) and its own figures, and hands it on
+        # as the checkpoint's stat; the submitting thread's queue wait lands
+        # in it too, whenever the job runs
+        stat = {"queue_wait_s": 0.0}
+        write = self._make_job(payload)
+
+        def job(store):
+            with store.counting(stat):
+                stat.update(write(store))
+            return stat
         if self.writer is not None:
-            return self.writer.submit_job(payload["key"], job, block=block)
+            # a blocking put waits here while the writer's queue is full;
+            # a non-blocking one returns at once and counts no wait
+            with span("flor.ckpt.queue_wait", stat if block else None,
+                      "queue_wait_s", ckpt=payload["key"]):
+                return self.writer.submit_job(payload["key"], job,
+                                              block=block)
         t0 = time.perf_counter()
-        stat = job(self.store)
+        job(self.store)
         stat["materialize_s"] = time.perf_counter() - t0
         self._materialized(stat)
         return True
@@ -376,20 +394,20 @@ class CheckpointPipeline:
                 # training thread
                 transferred = 0
                 changed_n = 0
+                copy_s = 0.0
                 for leaf in payload["leaves"]:
                     h = leaf.pop("handle", None)
                     if h is None:              # zero-byte leaf
                         continue
                     d = self.tracker.finalize(h)
-                    idx_keep, chunks_keep, encs_keep, t_bytes = \
-                        _encode_changed(d, leaf, payload["chunk_words"])
-                    leaf["changed_idx"] = idx_keep
-                    leaf["chunks"] = chunks_keep
-                    leaf["chunk_encs"] = encs_keep
+                    t_bytes, n_changed = _attach_changed(
+                        d, leaf, payload["chunk_words"], payload["key"])
                     transferred += t_bytes
-                    changed_n += len(idx_keep)
+                    changed_n += n_changed
+                    copy_s += d["copy_s"]
                 payload["transferred_bytes"] = transferred
                 payload["changed_chunks"] = changed_n
+                payload["copy_s"] = copy_s
             entropy_s = sum(self._entropy_pass(leaf)
                             for leaf in payload["leaves"])
             hashes_map = self._hashes.setdefault(scope, {})
@@ -468,6 +486,7 @@ class CheckpointPipeline:
             return {"key": payload["key"], "kind": payload["kind"],
                     "parent": payload["parent"],
                     "transferred_bytes": payload["transferred_bytes"],
+                    "copy_s": payload["copy_s"],
                     "logical_bytes": payload["logical_bytes"],
                     "changed_chunks": payload["changed_chunks"],
                     "total_chunks": payload["total_chunks"],
@@ -550,6 +569,7 @@ class CheckpointPipeline:
         layout: list[dict] = []        # global-manifest leaves
         rollback: list[tuple[str, Any]] = []
         transferred = 0
+        copy_s = 0.0
         logical = 0
         changed_chunks_n = 0
         total_chunks_n = 0
@@ -607,16 +627,15 @@ class CheckpointPipeline:
                 t0 = time.perf_counter()
                 if self.overlap:
                     ent["handle"] = self.tracker.delta_dispatch(
-                        tpath, _fp_view(local), **dkw)
+                        tpath, _fp_view(local), ckpt=key, **dkw)
                 else:
-                    d = self.tracker.delta(tpath, _fp_view(local), **dkw)
-                    idx_keep, chunks_keep, encs_keep, t_bytes = \
-                        _encode_changed(d, ent, self.chunk_words)
-                    ent["changed_idx"] = idx_keep
-                    ent["chunks"] = chunks_keep
-                    ent["chunk_encs"] = encs_keep
+                    d = self.tracker.delta(tpath, _fp_view(local),
+                                           ckpt=key, **dkw)
+                    t_bytes, n_changed = _attach_changed(
+                        d, ent, self.chunk_words, key)
                     transferred += t_bytes
-                    changed_chunks_n += len(idx_keep)
+                    changed_chunks_n += n_changed
+                    copy_s += d["copy_s"]
                 # per-host foreground cost: hosts run concurrently in a
                 # real deployment, so the simulated per-checkpoint wall is
                 # max over hosts, not the serial sum this process pays
@@ -637,6 +656,7 @@ class CheckpointPipeline:
             "treedef": str(treedef), "chunk_words": self.chunk_words,
             "entries": entries, "layout": layout, "overlap": self.overlap,
             "transferred_bytes": None if self.overlap else transferred,
+            "copy_s": None if self.overlap else copy_s,
             "logical_bytes": logical,
             "changed_chunks": None if self.overlap else changed_chunks_n,
             "total_chunks": total_chunks_n,
@@ -678,24 +698,24 @@ class CheckpointPipeline:
         if payload.get("overlap"):
             transferred = 0
             changed_n = 0
+            copy_s = 0.0
             for ent in payload["entries"]:
                 h = ent.pop("handle", None)
                 if h is None:
                     continue
                 t0 = time.perf_counter()
                 d = self.tracker.finalize(h)
-                idx_keep, chunks_keep, encs_keep, t_bytes = _encode_changed(
-                    d, ent, payload["chunk_words"])
-                ent["changed_idx"] = idx_keep
-                ent["chunks"] = chunks_keep
-                ent["chunk_encs"] = encs_keep
+                t_bytes, n_changed = _attach_changed(
+                    d, ent, payload["chunk_words"], payload["key"])
                 transferred += t_bytes
-                changed_n += len(idx_keep)
+                changed_n += n_changed
+                copy_s += d["copy_s"]
                 ss = payload["shard_stall_s"]
                 ss[ent["hid"]] = ss.get(ent["hid"], 0.0) \
                     + (time.perf_counter() - t0)
             payload["transferred_bytes"] = transferred
             payload["changed_chunks"] = changed_n
+            payload["copy_s"] = copy_s
         entropy_s = sum(self._entropy_pass(ent)
                         for ent in payload["entries"])
         hashes_map = self._hashes.setdefault(scope, {})
@@ -802,6 +822,7 @@ class CheckpointPipeline:
                 "stitched": stitched,
                 "parent": parent,
                 "transferred_bytes": payload["transferred_bytes"],
+                "copy_s": payload["copy_s"],
                 "logical_bytes": payload["logical_bytes"],
                 "changed_chunks": payload["changed_chunks"],
                 "total_chunks": payload["total_chunks"],
@@ -1038,6 +1059,19 @@ class CheckpointPipeline:
     @property
     def stats(self) -> list[dict]:
         return list(self._stats)
+
+
+def _attach_changed(d: dict, lmeta: dict, chunk_words: int,
+                    ckpt: str) -> tuple[int, int]:
+    """Encode one finalized delta's changed rows inside a
+    ``flor.ckpt.encode`` span and set them on the leaf entry as
+    ``changed_idx``, ``chunks`` and ``chunk_encs``. Returns
+    (transferred_bytes, changed chunk count)."""
+    with span("flor.ckpt.encode", ckpt=ckpt):
+        idx, chunks, encs, t_bytes = _encode_changed(d, lmeta, chunk_words)
+    lmeta["changed_idx"], lmeta["chunks"], lmeta["chunk_encs"] = \
+        idx, chunks, encs
+    return t_bytes, len(idx)
 
 
 def _encode_changed(d: dict, lmeta: dict, chunk_words: int):

@@ -60,6 +60,7 @@ never be silently inherited by a descendant run.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -74,6 +75,9 @@ from repro.utils.codec import Compressor, pack_obj, unpack_obj
 CHUNK = 4 * 1024 * 1024
 
 MANIFEST_VERSION = 3
+
+# the seconds a ``CheckpointStore.counting`` block adds up
+WRITE_COUNTERS = ("hash_s", "compress_s", "file_s")
 
 _CURRENT_RUN = object()          # sentinel: list_keys() default namespace
 
@@ -133,6 +137,8 @@ class CheckpointStore:
         os.makedirs(os.path.join(root, "meta"), exist_ok=True)
         self._codec = Compressor(level=compress_level)
         self._lock = threading.Lock()
+        # per-thread counters dict of an open ``counting`` block
+        self._tl = threading.local()
         # objects/<h[:2]>/ (and manifest-namespace) fan-out dirs, cached to
         # avoid a mkdir syscall on every chunk (the delta pipeline writes
         # many small chunks)
@@ -211,17 +217,46 @@ class CheckpointStore:
                 return p
         return None
 
+    @contextlib.contextmanager
+    def counting(self, counters: dict):
+        """Within the block, this thread's ``put_chunk`` and
+        ``put_manifest`` add their seconds into ``counters`` (the keys of
+        ``WRITE_COUNTERS``, started at 0): ``hash_s`` blake2b, ``compress_s``
+        the codec, ``file_s`` the existence check, directory creation, tmp
+        write and rename of chunks, and the manifest write."""
+        for k in WRITE_COUNTERS:
+            counters.setdefault(k, 0.0)
+        outer = getattr(self._tl, "counters", None)
+        self._tl.counters = counters
+        try:
+            yield counters
+        finally:
+            self._tl.counters = outer
+
+    def _tally(self, hash_s: float, compress_s: float, file_s: float):
+        c = getattr(self._tl, "counters", None)
+        if c is not None:
+            c["hash_s"] += hash_s
+            c["compress_s"] += compress_s
+            c["file_s"] += file_s
+
     def put_chunk(self, data: bytes, shard=None) -> tuple[str, int, bool]:
         """Store one content-addressed chunk (``shard`` selects a store
         shard's pool — bytes recorded on a host land on that host's disk).
         Returns (hash, compressed_bytes_written, was_new)."""
+        t0 = time.perf_counter()
         h = _hash(data)
+        t1 = time.perf_counter()
         path = self._chunk_path(h, shard)
         if os.path.exists(path):
+            self._tally(t1 - t0, 0.0, time.perf_counter() - t1)
             return h, 0, False
         self._ensure_dir(os.path.dirname(path))
+        t2 = time.perf_counter()
         payload = self._codec.compress(data)
+        t3 = time.perf_counter()
         _atomic_write(path, payload)   # chunks are cross-run shared state
+        self._tally(t1 - t0, t3 - t2, t2 - t1 + time.perf_counter() - t3)
         return h, len(payload), True
 
     # kept under the old private name too — tests and older callers use it
@@ -265,10 +300,12 @@ class CheckpointStore:
     def put_manifest(self, manifest: dict, key: Optional[str] = None):
         """Atomically persist a manifest (crash-safe tmp+rename). ``key``
         defaults to the manifest's own (run-local) key."""
+        t0 = time.perf_counter()
         mpath = self._manifest_path(key if key is not None
                                     else manifest["key"])
         self._ensure_dir(os.path.dirname(mpath))
         _atomic_write(mpath, pack_obj(manifest))
+        self._tally(0.0, 0.0, time.perf_counter() - t0)
 
     def get_manifest(self, key: str) -> dict:
         with open(self._manifest_path(key), "rb") as f:

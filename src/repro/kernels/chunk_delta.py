@@ -9,6 +9,10 @@ HBM bandwidth on the VPU, so fingerprinting costs one read of the leaf.
 Tiling: the [G, B] uint32 view is processed in (TILE_G, B) VMEM blocks; B is
 the checkpoint chunk size in words (4 KiB chunks = 1024 words by default),
 TILE_G chosen so the block fits comfortably in VMEM (TILE_G * B * 4 bytes).
+
+Each ``pallas_call`` carries a ``name=``, which the TPU compiler gives the
+kernel's custom call, so a profiler trace shows ``fingerprint``,
+``fingerprint_changed`` and ``changed_mask`` whatever program calls them.
 """
 from __future__ import annotations
 
@@ -69,6 +73,7 @@ def fingerprint_pallas(x_u32: jnp.ndarray, *, interpret: bool = True,
         out_specs=pl.BlockSpec((tile_g, 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((G, 2), jnp.uint32),
         interpret=interpret,
+        name="fingerprint",
     )(x_u32)
 
 
@@ -101,6 +106,7 @@ def fingerprint_changed_pallas(x_u32: jnp.ndarray, prev: jnp.ndarray, *,
         out_shape=[jax.ShapeDtypeStruct((G, 2), jnp.uint32),
                    jax.ShapeDtypeStruct((G, 1), jnp.int32)],
         interpret=interpret,
+        name="fingerprint_changed",
     )(x_u32, prev)
     return digest, mask.reshape(G)
 
@@ -124,4 +130,5 @@ def changed_mask_pallas(digest: jnp.ndarray, prev: jnp.ndarray, *,
         out_specs=pl.BlockSpec((tile_g,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((G,), jnp.int32),
         interpret=interpret,
+        name="changed_mask",
     )(digest, prev)

@@ -96,7 +96,7 @@ def changed_chunks(digest, prev_digest):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_words",))
-def gather_changed_blocks(x, idx, chunk_words: int = CHUNK_WORDS):
+def gather_changed_rows(x, idx, chunk_words: int = CHUNK_WORDS):
     """[C, W] u32 rows of the block view of `x` selected by `idx` — the only
     device->host payload the delta pipeline transfers per leaf. Deliberately
     a SEPARATE traced computation from the fingerprint: a fused

@@ -4,7 +4,9 @@ Backs three subsystems: checkpoint compression (optimizer moments tolerate
 blockwise int8; error-bounded), the cross-pod gradient-compression codec
 (parallel/compression.py), and the fused checkpoint fast path
 (``gather_quantize_pallas``: changed chunk rows leave the device already
-wire-format, via scalar-prefetch gather + quantize in one VMEM pass).
+wire-format, via scalar-prefetch gather + quantize in one VMEM pass). The
+two record-path gathers are named ``gather_quantize8`` and
+``gather_quantize4`` in a profiler trace.
 """
 from __future__ import annotations
 
@@ -86,6 +88,7 @@ def gather_quantize_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
         out_shape=[jax.ShapeDtypeStruct((C, n_sub, block), jnp.int8),
                    jax.ShapeDtypeStruct((C, n_sub, 1), jnp.float32)],
         interpret=interpret,
+        name="gather_quantize8",
     )(idx, x.reshape(G, n_sub, block))
     return q.reshape(C, W), scale.reshape(C, n_sub)
 
@@ -143,6 +146,7 @@ def gather_quantize4_pallas(x: jnp.ndarray, idx: jnp.ndarray, *,
         out_shape=[jax.ShapeDtypeStruct((C, m, L), jnp.uint8),
                    jax.ShapeDtypeStruct((C, 2, m, 1), jnp.float32)],
         interpret=interpret,
+        name="gather_quantize4",
     )(idx, x.reshape(G, 2, m, L))
     return packed.reshape(C, W // 2), scale.reshape(C, 2 * m)[:, :n_sub]
 

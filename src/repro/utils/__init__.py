@@ -1,10 +1,10 @@
 """Shared utilities: pytree helpers, timing, formatting."""
 from repro.utils.pytree import (tree_bytes, tree_leaves_with_paths, path_str,
                                 tree_allclose, tree_size)
-from repro.utils.timing import Stopwatch, EMA
+from repro.utils.timing import EMA, span
 
 __all__ = ["tree_bytes", "tree_leaves_with_paths", "path_str", "tree_allclose",
-           "tree_size", "Stopwatch", "EMA", "fmt_bytes"]
+           "tree_size", "span", "EMA", "fmt_bytes"]
 
 
 def fmt_bytes(n: float) -> str:

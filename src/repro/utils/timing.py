@@ -1,30 +1,31 @@
-"""Wall-clock instrumentation for the Flor adaptive-checkpointing controller."""
+"""Wall-clock instrumentation: profiler spans with optional second counters,
+and the EMA the Flor adaptive-checkpointing controller smooths with."""
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
+
+import jax
 
 
-class Stopwatch:
-    """Context-manager stopwatch. `elapsed` in seconds after the block."""
-
-    def __init__(self):
-        self.elapsed = 0.0
-        self._t0 = None
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self._t0
-        return False
-
-    def start(self):
-        self._t0 = time.perf_counter()
-
-    def stop(self) -> float:
-        self.elapsed = time.perf_counter() - self._t0
-        return self.elapsed
+@contextmanager
+def span(name: str, counters: dict | None = None, key: str | None = None,
+         **meta):
+    """A named host span on the profiler's clock: while a ``jax.profiler``
+    trace runs it lands in the same ``.xplane.pb`` as the device planes;
+    otherwise it costs an inactive TraceMe check. ``meta`` rides on the
+    event (``None`` values are left out). When ``counters`` is given, the
+    span's elapsed ``perf_counter`` seconds are added into
+    ``counters[key]``."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(
+                name, **{k: v for k, v in meta.items() if v is not None}):
+            yield
+    finally:
+        if counters is not None:
+            counters[key] = counters.get(key, 0.0) \
+                + time.perf_counter() - t0
 
 
 class EMA:

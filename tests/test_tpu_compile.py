@@ -90,3 +90,40 @@ def test_gather_quantize_compiles(one_chip, leaf, cw, bits, all_rows):
     _compile_text(lambda x, i: kernel(ops._padded_float_blocks(x, cw), i,
                                       block=min(block, cw), interpret=False),
                   x, idx)
+
+
+# the custom call each record-path kernel compiles to is named after its
+# ``name=``, so a trace shows the kernel whatever program calls it; the
+# fused pass must keep the prefix the fingerprint roofline reader matches
+NAMED = {
+    "fingerprint": lambda x, i, p: fingerprint_pallas(
+        ops._as_u32_blocks(x, PIPELINE_CHUNK_WORDS), interpret=False),
+    "fingerprint_changed": lambda x, i, p: fingerprint_changed_pallas(
+        ops._as_u32_blocks(x, PIPELINE_CHUNK_WORDS), p, interpret=False),
+    "gather_quantize8": lambda x, i, p: gather_quantize_pallas(
+        ops._padded_float_blocks(x, PIPELINE_CHUNK_WORDS), i,
+        block=Q8_BLOCK, interpret=False),
+    "gather_quantize4": lambda x, i, p: gather_quantize4_pallas(
+        ops._padded_float_blocks(x, PIPELINE_CHUNK_WORDS), i,
+        block=Q4_BLOCK, interpret=False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_record_kernels_carry_stable_names(one_chip, name):
+    shape, dtype = LEAVES["mlp_bf16"]
+    g = _rows(shape, dtype, PIPELINE_CHUNK_WORDS)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    prev = jax.ShapeDtypeStruct((g, 2), jnp.uint32, sharding=one_chip)
+    text = _compile_text(NAMED[name], x, idx, prev)
+    calls = [ln.split("=")[0].strip().lstrip("%") for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert calls and all(c.split(".")[0] == name for c in calls), calls
+
+
+def test_raw_gather_program_is_named():
+    x = jax.ShapeDtypeStruct((64, 1024), jnp.float32)
+    idx = jax.ShapeDtypeStruct((8,), jnp.int32)
+    text = ops.gather_changed_rows.lower(x, idx, chunk_words=1024).as_text()
+    assert "@jit_gather_changed_rows" in text
