@@ -366,7 +366,9 @@ class CheckpointPipeline:
         write = self._make_job(payload)
 
         def job(store):
-            with store.counting(stat):
+            # the job's chunks go into a few packs, published before the
+            # manifests that name them
+            with store.counting(stat), store.packing():
                 stat.update(write(store))
             return stat
         if self.writer is not None:

@@ -57,6 +57,13 @@ manifest's chain, so deleting one run's registration reclaims only what no
 surviving run inherits. Chunk writes are tmp+rename atomic: chunks are
 cross-run shared state, and a truncated chunk from a killed writer must
 never be silently inherited by a descendant run.
+
+Chunks are written into pack files (``checkpoint/packs.py``), a few per
+writer job, not one file per chunk: inside ``packing()`` a thread's new
+chunks go into one open pack per pool, published (renamed into place) at
+rollover, by ``put_manifest`` before the manifest that names them, and when
+the block ends. Loose ``<h>.zst`` files of older stores stay readable and
+collectable.
 """
 from __future__ import annotations
 
@@ -70,14 +77,16 @@ from typing import Any, Iterable, Optional
 
 import numpy as np
 
+from repro.checkpoint import packs
 from repro.utils.codec import Compressor, pack_obj, unpack_obj
 
 CHUNK = 4 * 1024 * 1024
 
 MANIFEST_VERSION = 3
 
-# the seconds a ``CheckpointStore.counting`` block adds up
-WRITE_COUNTERS = ("hash_s", "compress_s", "file_s")
+# what a ``CheckpointStore.counting`` block adds up: seconds, and files
+WRITE_COUNTERS = {"hash_s": 0.0, "compress_s": 0.0, "file_s": 0.0,
+                  "files_written": 0}
 
 _CURRENT_RUN = object()          # sentinel: list_keys() default namespace
 
@@ -103,10 +112,12 @@ def np_dtype(name: str) -> np.dtype:
 
 class CheckpointStore:
     """Thread-safe on-disk store, shareable across runs. Layout:
-       <root>/objects/<h[:2]>/<h>.zst        — chunk payloads (shared pool)
+       <root>/objects/packs/<id>.pack        — chunk payloads (shared pool),
+                                               many per pack (packs.py)
+       <root>/objects/<h[:2]>/<h>.zst        — loose chunks of older stores
        <root>/shards/<host>/objects/...      — per-store-shard pools (mesh
                                                record: each simulated host's
-                                               local disk; same addressing)
+                                               local disk; same layout)
        <root>/manifests/<key>.msgpack        — un-namespaced manifests
        <root>/manifests/<run>/<key>.msgpack  — per-run manifest namespaces
        <root>/meta/[<run>/]<name>.json       — run-level metadata
@@ -136,9 +147,12 @@ class CheckpointStore:
         os.makedirs(os.path.join(root, "manifests"), exist_ok=True)
         os.makedirs(os.path.join(root, "meta"), exist_ok=True)
         self._codec = Compressor(level=compress_level)
-        self._lock = threading.Lock()
-        # per-thread counters dict of an open ``counting`` block
+        self._lock = threading.RLock()
+        # per thread: the counters dict of an open ``counting`` block, and
+        # the open packs of a ``packing`` block
         self._tl = threading.local()
+        # what this handle knows of each chunk pool, by shard id (None: flat)
+        self._pools: dict[Optional[str], _Pool] = {}
         # objects/<h[:2]>/ (and manifest-namespace) fan-out dirs, cached to
         # avoid a mkdir syscall on every chunk (the delta pipeline writes
         # many small chunks)
@@ -173,15 +187,17 @@ class CheckpointStore:
             self._dirs.add(d)
 
     # ------------------------------------------------------------ chunks --
-    def _chunk_path(self, h: str, shard=None) -> str:
-        """On-disk path of a chunk: the flat shared pool, or (``shard``)
-        one store shard's pool — ``shards/<h(ost)>/objects/`` — which in a
-        real deployment is that host's local disk."""
+    def _pool_dir(self, shard=None) -> str:
+        """A chunk pool: the flat shared pool, or (``shard``) one store
+        shard's pool — ``shards/<h(ost)>/objects/`` — which in a real
+        deployment is that host's local disk."""
         if shard is None:
-            base = os.path.join(self.root, "objects")
-        else:
-            base = os.path.join(self.root, "shards", str(shard), "objects")
-        return os.path.join(base, h[:2], h + ".zst")
+            return os.path.join(self.root, "objects")
+        return os.path.join(self.root, "shards", str(shard), "objects")
+
+    def _chunk_path(self, h: str, shard=None) -> str:
+        """Path of a loose chunk file (stores written before packs)."""
+        return os.path.join(self._pool_dir(shard), h[:2], h + ".zst")
 
     def _shard_ids(self) -> list[str]:
         """Store shards with a chunk pool on disk (sorted numerically when
@@ -194,38 +210,105 @@ class CheckpointStore:
         return sorted(ids, key=lambda s: (not s.isdigit(),
                                           int(s) if s.isdigit() else s))
 
+    def _pool(self, shard=None) -> "_Pool":
+        key = None if shard is None else str(shard)
+        pool = self._pools.get(key)
+        if pool is None:
+            with self._lock:
+                pool = self._pools.setdefault(key,
+                                              _Pool(self._pool_dir(key)))
+        return pool
+
+    def _scan(self, pool: "_Pool"):
+        with self._lock:
+            pool.scan()
+
+    def _all_pools(self) -> list["_Pool"]:
+        return [self._pool(s) for s in [None] + self._shard_ids()]
+
+    def _pool_order(self, shard=None) -> list:
+        """Pools to look in for a chunk: ``shard``'s, the preferred shards',
+        the flat pool, then every other shard pool. Content addressing makes
+        any copy valid; the fallback keeps reads working when a tree is
+        restored on a different mesh shape than recorded it."""
+        order = [] if shard is None else [str(shard)]
+        for s in self.prefer_shards + [None] + self._shard_ids():
+            if s not in order:
+                order.append(s)
+        return order
+
     def _find_chunk(self, h: str, shard=None) -> Optional[str]:
-        """Locate a chunk, preferring ``shard``'s pool, then the flat pool,
-        then every other shard pool. Content addressing makes any copy
-        valid; the fallback keeps reads working when a tree is restored on
-        a different mesh shape than recorded it."""
-        cands = []
-        if shard is not None:
-            cands.append(self._chunk_path(h, shard))
-        for s in self.prefer_shards:
-            if shard is None or str(shard) != s:
-                cands.append(self._chunk_path(h, s))
-        cands.append(self._chunk_path(h))
-        seen = {str(shard)} if shard is not None else set()
-        seen.update(self.prefer_shards)
-        for s in self._shard_ids():
-            if s in seen:
-                continue
-            cands.append(self._chunk_path(h, s))
-        for p in cands:
-            if os.path.exists(p):
+        """Locate a loose chunk file, in ``_pool_order`` (``_locate``'s
+        fallback, once it has scanned every pool)."""
+        for s in self._pool_order(shard):
+            p = self._chunk_path(h, s)
+            if self._pool(s).loose and os.path.exists(p):
                 return p
         return None
+
+    def _locate(self, h: str, shard=None) -> Optional[tuple]:
+        """Where chunk ``h`` lives, as (path, offset, length): a record of
+        a published pack, or a loose file (offset 0, length None); None if
+        nowhere. Pools are tried in ``_pool_order``. On a miss the packs
+        published since this handle last looked (by other handles,
+        processes or runs sharing the root) are read, then loose files."""
+        first = self._pool(shard)
+        if not first.scanned:
+            self._scan(first)
+        loc = first.where.get(h)
+        if loc is not None:
+            return loc
+        order = [self._pool(s) for s in self._pool_order(shard)]
+        for rescan in (False, True):
+            for pool in order:
+                if rescan or not pool.scanned:
+                    self._scan(pool)
+                loc = pool.where.get(h)
+                if loc is not None:
+                    return loc
+        p = self._find_chunk(h, shard)
+        return None if p is None else (p, 0, None)
+
+    def _stored_in(self, pool: "_Pool", h: str, shard) -> bool:
+        """Whether ``h`` is already durable in ``pool`` (the dedup check).
+        Another handle's gc may have rewritten or removed the pack this
+        handle knows it from, so a hit is checked on disk, and the pool
+        read again if the pack is gone."""
+        if not pool.scanned:
+            self._scan(pool)
+        loc = pool.where.get(h)
+        if loc is not None:
+            if os.path.exists(loc[0]):
+                return True
+            self._scan(pool)
+            if h in pool.where:
+                return True
+        return pool.loose and os.path.exists(self._chunk_path(h, shard))
+
+    def _chunk_cost(self, h: str) -> int:
+        """On-disk bytes chunk ``h`` accounts for (``packs.record_cost``,
+        or a loose file's size); 0 if it is nowhere."""
+        loc = self._locate(h)
+        if loc is None:
+            return 0
+        path, off, n = loc
+        if n is not None:
+            return packs.record_cost(off, n)
+        try:
+            return os.path.getsize(path)
+        except OSError:
+            return 0
 
     @contextlib.contextmanager
     def counting(self, counters: dict):
         """Within the block, this thread's ``put_chunk`` and
-        ``put_manifest`` add their seconds into ``counters`` (the keys of
+        ``put_manifest`` add into ``counters`` (the keys of
         ``WRITE_COUNTERS``, started at 0): ``hash_s`` blake2b, ``compress_s``
-        the codec, ``file_s`` the existence check, directory creation, tmp
-        write and rename of chunks, and the manifest write."""
-        for k in WRITE_COUNTERS:
-            counters.setdefault(k, 0.0)
+        the codec, ``file_s`` the dedup check, pack writes and publication,
+        and the manifest write; ``files_written`` the packs and manifests
+        published."""
+        for k, zero in WRITE_COUNTERS.items():
+            counters.setdefault(k, zero)
         outer = getattr(self._tl, "counters", None)
         self._tl.counters = counters
         try:
@@ -240,51 +323,169 @@ class CheckpointStore:
             c["compress_s"] += compress_s
             c["file_s"] += file_s
 
+    def _count_file(self):
+        c = getattr(self._tl, "counters", None)
+        if c is not None:
+            c["files_written"] += 1
+
+    @contextlib.contextmanager
+    def packing(self):
+        """Within the block, this thread's new chunks go into one open pack
+        per pool instead of a pack each. ``put_manifest`` publishes them
+        before the manifest that may name their chunks, as do a pack's
+        passing ``packs.PACK_BYTES`` and the block's end. A nested block
+        joins the outer one."""
+        if getattr(self._tl, "packs", None) is not None:
+            yield
+            return
+        self._tl.packs = {}
+        try:
+            yield
+        finally:
+            t0 = time.perf_counter()
+            try:
+                self._publish_open()
+            finally:
+                self._tl.packs = None
+                self._tally(0.0, 0.0, time.perf_counter() - t0)
+
+    def _publish(self, pool: "_Pool", w: packs.PackWriter) -> int:
+        """Publish one open pack and index it; returns its size."""
+        try:
+            size = w.publish()
+        except BaseException:
+            w.abort()
+            raise
+        with self._lock:
+            pool.add(w.path, w.entries)
+        self._count_file()
+        return size
+
+    def _publish_open(self):
+        """Publish this thread's open packs, if it is in a ``packing``
+        block. Should one fail, the others are dropped too."""
+        open_packs = getattr(self._tl, "packs", None)
+        try:
+            while open_packs:
+                self._publish(*open_packs.popitem())
+        finally:
+            for w in (open_packs or {}).values():
+                w.abort()
+            if open_packs:
+                open_packs.clear()
+
     def put_chunk(self, data: bytes, shard=None) -> tuple[str, int, bool]:
         """Store one content-addressed chunk (``shard`` selects a store
         shard's pool — bytes recorded on a host land on that host's disk).
-        Returns (hash, compressed_bytes_written, was_new)."""
+        Returns (hash, bytes_written, was_new): ``bytes_written`` is the
+        chunk's ``packs.record_cost``, so over a job it sums to the size of
+        the packs the job published. Inside ``packing()`` the chunk joins
+        this thread's open pack for the pool; outside, it is published at
+        once in a pack of its own."""
         t0 = time.perf_counter()
         h = _hash(data)
         t1 = time.perf_counter()
-        path = self._chunk_path(h, shard)
-        if os.path.exists(path):
+        pool = self._pool(shard)
+        open_packs = getattr(self._tl, "packs", None)
+        w = None if open_packs is None else open_packs.get(pool)
+        if (w is not None and h in w.entries) \
+                or self._stored_in(pool, h, shard):
             self._tally(t1 - t0, 0.0, time.perf_counter() - t1)
             return h, 0, False
-        self._ensure_dir(os.path.dirname(path))
         t2 = time.perf_counter()
         payload = self._codec.compress(data)
         t3 = time.perf_counter()
-        _atomic_write(path, payload)   # chunks are cross-run shared state
+        if w is None:
+            self._ensure_dir(pool.packs_dir)
+            w = packs.PackWriter(pool.packs_dir)
+            if open_packs is not None:
+                open_packs[pool] = w
+        try:
+            nb = w.add(h, payload)
+        except BaseException:
+            # the pack's file no longer matches its entries: drop it
+            if open_packs is not None:
+                open_packs.pop(pool, None)
+            w.abort()
+            raise
+        if open_packs is None or w.size >= packs.PACK_BYTES:
+            if open_packs is not None:
+                del open_packs[pool]
+            self._publish(pool, w)
         self._tally(t1 - t0, t3 - t2, t2 - t1 + time.perf_counter() - t3)
-        return h, len(payload), True
+        return h, nb, True
 
     # kept under the old private name too — tests and older callers use it
     _put_chunk = put_chunk
 
     def get_chunk(self, h: str, shard=None) -> bytes:
-        path = self._chunk_path(h, shard)
-        if not os.path.exists(path):
-            found = self._find_chunk(h, shard)
-            if found is None:
-                raise FileNotFoundError(
-                    f"chunk {h} not in any pool of {self.root}")
-            path = found
-        with open(path, "rb") as f:
-            return self._codec.decompress(f.read())
+        for _ in range(2):
+            loc = self._locate(h, shard)
+            if loc is None:
+                break
+            path, off, n = loc
+            try:
+                if n is None:
+                    with open(path, "rb") as f:
+                        payload = f.read()
+                else:
+                    payload = packs.read_record(path, off, n)
+            except FileNotFoundError:
+                # another handle's gc rewrote or removed the pack
+                for pool in list(self._pools.values()):
+                    if path in pool.packs:
+                        self._scan(pool)
+                continue
+            return self._codec.decompress(payload)
+        raise FileNotFoundError(f"chunk {h} not in any pool of {self.root}")
 
     _get_chunk = get_chunk
 
     def _iter_chunk_files(self):
-        """Every chunk file across the flat pool and all shard pools as
-        (path, filename) — the single sweep gc/stats share."""
-        pools = [os.path.join(self.root, "objects")]
-        pools += [os.path.join(self.root, "shards", s, "objects")
-                  for s in self._shard_ids()]
-        for pool in pools:
-            for dirpath, _, files in os.walk(pool):
+        """Every file across the flat pool and all shard pools as (pool,
+        path, filename) — the single sweep gc/stats share."""
+        for pool in self._all_pools():
+            for dirpath, _, files in os.walk(pool.path):
                 for fn in files:
-                    yield os.path.join(dirpath, fn), fn
+                    yield pool, os.path.join(dirpath, fn), fn
+
+    def _pack_index(self, pool: "_Pool", path: str) -> Optional[dict]:
+        """A published pack's index, read once per handle; None if it is
+        gone or not a whole pack."""
+        idx = pool.packs.get(path)
+        if idx is None:
+            try:
+                idx = packs.read_index(path)
+            except (FileNotFoundError, ValueError):
+                return None
+            with self._lock:
+                pool.add(path, idx)
+        return idx
+
+    def _rewrite_pack(self, pool: "_Pool", path: str, live: dict) -> int:
+        """Replace a pack by one holding only its ``live`` records ({hash:
+        (offset, length)}), or remove it if none are. The new pack is
+        published before the old one goes, so every live chunk stays
+        readable throughout. Returns the bytes freed."""
+        freed = os.path.getsize(path)
+        if live:
+            w = packs.PackWriter(pool.packs_dir)
+            try:
+                fd = os.open(path, os.O_RDONLY)
+                try:
+                    for h, (off, n) in sorted(live.items(),
+                                              key=lambda e: e[1][0]):
+                        w.add(h, os.pread(fd, n, off))
+                finally:
+                    os.close(fd)
+            except BaseException:
+                w.abort()
+                raise
+            freed -= self._publish(pool, w)
+        os.remove(path)
+        with self._lock:
+            pool.forget(path)
+        return freed
 
     # --------------------------------------------------------- manifests --
     def _mpath(self, rid_safe: Optional[str], key_safe: str) -> str:
@@ -298,13 +499,17 @@ class CheckpointStore:
         return self._mpath(*self._norm_key(key))
 
     def put_manifest(self, manifest: dict, key: Optional[str] = None):
-        """Atomically persist a manifest (crash-safe tmp+rename). ``key``
-        defaults to the manifest's own (run-local) key."""
+        """Atomically persist a manifest (crash-safe tmp+rename), after
+        publishing this thread's open packs, so a manifest never names a
+        chunk that is not on disk yet. ``key`` defaults to the manifest's
+        own (run-local) key."""
         t0 = time.perf_counter()
+        self._publish_open()
         mpath = self._manifest_path(key if key is not None
                                     else manifest["key"])
         self._ensure_dir(os.path.dirname(mpath))
         _atomic_write(mpath, pack_obj(manifest))
+        self._count_file()
         self._tally(0.0, 0.0, time.perf_counter() - t0)
 
     def get_manifest(self, key: str) -> dict:
@@ -321,15 +526,29 @@ class CheckpointStore:
             except FileNotFoundError:
                 m = None
             if m is not None:
-                for h in _manifest_chunk_hashes(m):
-                    try:
-                        os.remove(self._chunk_path(h))
-                    except FileNotFoundError:
-                        pass
+                self._drop_chunks(set(_manifest_chunk_hashes(m)))
         try:
             os.remove(self._manifest_path(key))
         except FileNotFoundError:
             pass
+
+    def _drop_chunks(self, hashes: set):
+        """Remove chunks from the flat pool: their loose files, and their
+        records from the packs that hold them."""
+        with self._lock:
+            pool = self._pool()
+            self._scan(pool)
+            if pool.loose:
+                for h in hashes:
+                    try:
+                        os.remove(self._chunk_path(h))
+                    except FileNotFoundError:
+                        pass
+            for path, idx in list(pool.packs.items()):
+                if not hashes.isdisjoint(idx):
+                    self._rewrite_pack(pool, path,
+                                       {h: loc for h, loc in idx.items()
+                                        if h not in hashes})
 
     def _load_tuple(self, t: tuple, cache: dict) -> Optional[dict]:
         """Memoized manifest read by normalized (rid, key) tuple; None for a
@@ -482,31 +701,32 @@ class CheckpointStore:
         total_bytes = 0
         new_chunks = 0
         total_chunks = 0
-        for path, leaf in flat:
-            arr = _leaf_to_np(leaf)
-            raw = arr.tobytes()
-            chunks = []
-            for off in range(0, max(len(raw), 1), CHUNK):
-                piece = raw[off:off + CHUNK]
-                h, nb, new = self.put_chunk(piece)
-                chunks.append(h)
-                new_bytes += nb
-                total_bytes += len(piece)
-                new_chunks += int(new)
-                total_chunks += 1
-            leaves.append({
-                "path": jax.tree_util.keystr(path),
-                "dtype": str(arr.dtype),
-                "shape": list(arr.shape),
-                "chunks": chunks,
-            })
-        manifest = {
-            "key": self._split_key(key)[1],
-            "treedef": str(treedef),
-            "leaves": leaves,
-            "meta": meta or {},
-        }
-        self.put_manifest(manifest, key=key)
+        with self.packing():
+            for path, leaf in flat:
+                arr = _leaf_to_np(leaf)
+                raw = arr.tobytes()
+                chunks = []
+                for off in range(0, max(len(raw), 1), CHUNK):
+                    piece = raw[off:off + CHUNK]
+                    h, nb, new = self.put_chunk(piece)
+                    chunks.append(h)
+                    new_bytes += nb
+                    total_bytes += len(piece)
+                    new_chunks += int(new)
+                    total_chunks += 1
+                leaves.append({
+                    "path": jax.tree_util.keystr(path),
+                    "dtype": str(arr.dtype),
+                    "shape": list(arr.shape),
+                    "chunks": chunks,
+                })
+            manifest = {
+                "key": self._split_key(key)[1],
+                "treedef": str(treedef),
+                "leaves": leaves,
+                "meta": meta or {},
+            }
+            self.put_manifest(manifest, key=key)
         return {"key": key, "total_bytes": total_bytes, "new_bytes": new_bytes,
                 "total_chunks": total_chunks, "new_chunks": new_chunks}
 
@@ -691,10 +911,15 @@ class CheckpointStore:
         chunks = 0
         stored = 0
         if include_chunks:
-            for p, fn in self._iter_chunk_files():
+            for pool, p, fn in self._iter_chunk_files():
                 if fn.endswith(".zst"):
                     chunks += 1
                     stored += os.path.getsize(p)
+                elif fn.endswith(packs.SUFFIX):
+                    idx = self._pack_index(pool, p)
+                    if idx is not None:
+                        chunks += len(idx)
+                        stored += os.path.getsize(p)
         out = {"manifests": n_manifests,
                "full_manifests": counts.get("full", 0),
                "delta_manifests": counts.get("delta", 0),
@@ -726,11 +951,7 @@ class CheckpointStore:
 
         def chunk_size(h: str) -> int:
             if h not in size_cache:
-                p = self._find_chunk(h)
-                try:
-                    size_cache[h] = os.path.getsize(p) if p else 0
-                except OSError:
-                    size_cache[h] = 0
+                size_cache[h] = self._chunk_cost(h)
             return size_cache[h]
 
         def add_leaves(leaves):
@@ -795,17 +1016,12 @@ class CheckpointStore:
         return hashes
 
     def chunk_bytes(self, hashes: Iterable[str]) -> int:
-        """On-disk (compressed) bytes of the given chunk hashes, wherever
-        they live (flat or shard pools); missing chunks count 0."""
-        total = 0
-        for h in hashes:
-            p = self._find_chunk(h)
-            if p is not None:
-                try:
-                    total += os.path.getsize(p)
-                except OSError:
-                    pass
-        return total
+        """On-disk bytes of the given chunk hashes, wherever they live
+        (flat or shard pools): a pack record with its index entry (and a
+        pack's first record its trailer, ``packs.record_cost``), or a loose
+        file; missing chunks count 0. Over the chunks a job wrote this is
+        the size of the packs it published."""
+        return sum(self._chunk_cost(h) for h in hashes)
 
     # ---------------------------------------------------------------- gc --
     def gc(self, live_keys: Iterable[str]) -> dict:
@@ -851,22 +1067,39 @@ class CheckpointStore:
             kept = deleted = deleted_bytes = deleted_tmp = 0
             now = time.time()
             # sweep the flat pool AND every store shard's pool — a chunk
-            # hash is live wherever it lives
-            for p, fn in self._iter_chunk_files():
-                if not fn.endswith(".zst"):
+            # hash is live wherever it lives. Within a pool one copy of a
+            # live chunk is kept (a loose file first, then the oldest pack);
+            # a pack left with no live record goes, and one that is partly
+            # live is rewritten with its live records only
+            kept_in: set[tuple] = set()
+            pack_files = []
+            for pool, p, fn in self._iter_chunk_files():
+                if fn.endswith(packs.SUFFIX):
+                    pack_files.append((fn, pool, p))
+                elif not fn.endswith(".zst"):
                     # stray .tmp from a KILLED writer (the in-process
                     # failure path unlinks its own): reclaim once aged —
-                    # a live writer holds a tmp for milliseconds, so the
-                    # age gate never races an in-flight _atomic_write
+                    # a live writer holds a tmp for far less, so the age
+                    # gate never races an in-flight write
                     deleted_tmp += _reclaim_stale_tmp(p, now)
-                    continue
-                h = fn[: -len(".zst")]
-                if h in referenced:
+                elif fn[: -len(".zst")] in referenced:
                     kept += 1
+                    kept_in.add((pool.path, fn[: -len(".zst")]))
                 else:
                     deleted_bytes += os.path.getsize(p)
                     os.remove(p)
                     deleted += 1
+            for _, pool, p in sorted(pack_files, key=lambda e: e[0]):
+                idx = self._pack_index(pool, p)
+                if idx is None:
+                    continue
+                keep = {h: loc for h, loc in idx.items()
+                        if h in referenced and (pool.path, h) not in kept_in}
+                kept_in.update((pool.path, h) for h in keep)
+                kept += len(keep)
+                if len(keep) < len(idx):
+                    deleted += len(idx) - len(keep)
+                    deleted_bytes += self._rewrite_pack(pool, p, keep)
             for dirpath, _, files in os.walk(os.path.join(self.root,
                                                           "manifests")):
                 for fn in files:
@@ -900,7 +1133,7 @@ class CheckpointStore:
 
     def stored_bytes(self) -> int:
         total = 0
-        for p, _ in self._iter_chunk_files():
+        for _, p, _ in self._iter_chunk_files():
             total += os.path.getsize(p)
         return total
 
@@ -936,7 +1169,7 @@ def _atomic_write(path: str, payload: bytes):
         raise
 
 
-STALE_TMP_S = 60.0       # a live _atomic_write holds its tmp far less
+STALE_TMP_S = 60.0       # a live writer holds its tmp far less
 
 
 def _reclaim_stale_tmp(path: str, now: float) -> int:
@@ -951,6 +1184,58 @@ def _reclaim_stale_tmp(path: str, now: float) -> int:
     except OSError:
         pass
     return 0
+
+
+class _Pool:
+    """What one handle knows of one chunk pool: the index of every pack it
+    has read, and whether the pool holds loose chunk files (stores written
+    before packs). The handle's lock guards every change."""
+
+    def __init__(self, path: str):
+        self.path = path
+        self.packs_dir = os.path.join(path, "packs")
+        self.packs: dict[str, dict[str, tuple[int, int]]] = {}
+        self.where: dict[str, tuple[str, int, int]] = {}   # h -> location
+        self.scanned = False
+        self.loose = False
+
+    def scan(self):
+        """Read the index of every pack published since the last scan, and
+        forget the packs that are gone."""
+        try:
+            names = os.listdir(self.packs_dir)
+        except FileNotFoundError:
+            names = []
+        on_disk = {os.path.join(self.packs_dir, n) for n in names
+                   if n.endswith(packs.SUFFIX)}
+        for p in set(self.packs) - on_disk:
+            self.forget(p)
+        for p in sorted(on_disk - set(self.packs)):
+            try:
+                self.add(p, packs.read_index(p))
+            except (FileNotFoundError, ValueError):
+                pass    # gc removed it since the listing; not a whole pack
+        if not self.scanned:
+            try:
+                self.loose = any(len(e) == 2 for e in os.listdir(self.path))
+            except FileNotFoundError:
+                pass
+            self.scanned = True
+
+    def add(self, path: str, index: dict):
+        self.packs[path] = index
+        for h, (off, n) in index.items():
+            self.where.setdefault(h, (path, off, n))
+
+    def forget(self, path: str):
+        for h in self.packs.pop(path, {}):
+            if self.where.get(h, (None,))[0] != path:
+                continue
+            del self.where[h]
+            for other, idx in self.packs.items():   # another copy?
+                if h in idx:
+                    self.where[h] = (other, *idx[h])
+                    break
 
 
 def _manifest_chunk_hashes(manifest: dict):

@@ -65,9 +65,11 @@ def test_store_counting_splits_put_chunk(store):
         first = dict(c)
         store.put_chunk(data)          # already there: no compress
     store.put_chunk(bytes(8))          # outside the block: not counted
-    assert set(c) == {"hash_s", "compress_s", "file_s"}
+    assert set(c) == {"hash_s", "compress_s", "file_s", "files_written"}
     assert all(v > 0 for v in first.values())
+    assert first["files_written"] == 1   # a pack of its own, at once
     assert c["compress_s"] == first["compress_s"]
+    assert c["files_written"] == 1
     assert c["hash_s"] > first["hash_s"] and c["file_s"] > first["file_s"]
     with store.counting(c):
         with store.counting({}):
@@ -75,6 +77,7 @@ def test_store_counting_splits_put_chunk(store):
         before = c["file_s"]
         store.put_manifest({"key": "m", "leaves": []})
     assert c["file_s"] > before        # the outer block counts again
+    assert c["files_written"] == 2
 
 
 def test_queue_wait_counts_the_blocked_submits(store):
