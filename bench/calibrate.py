@@ -62,9 +62,8 @@ def program_first_steps(system, traffic, seed, half_batch=False):
 def readings(cell, seeds, control_seeds, emit=print):
     """Every reading of one cell, as dicts; ``emit`` gets each as made."""
     from harness import checks, model
-    from references import dense_lm
-    dims = model.hf_dims(cell.config)
-    system = model.System(model.program_config(cell.config), cell.traffic)
+    dims = cell.family.dims(cell.config)
+    system = model.System(cell.family, cell.config, cell.traffic)
     opt = {"peak_lr": model.PEAK_LR, "warmup": model.WARMUP, "b1": model.B1,
            "b2": model.B2, "eps": model.ADAM_EPS,
            "weight_decay": model.WEIGHT_DECAY, "grad_clip": model.GRAD_CLIP}
@@ -83,13 +82,14 @@ def readings(cell, seeds, control_seeds, emit=print):
         state = system.init_state(model.seed_key(seed))
         frozen, train = state["frozen"], state["train"].params
         del state
-        ref = dense_lm.first_steps(dims, opt, frozen, train, cap.batches,
-                                   rows)
+        ref = cell.reference.first_steps(dims, opt, frozen, train,
+                                         cap.batches, rows)
         record("program", seed, checks.step_numbers(
             checks.program_readings(cap), ref))
         if i < control_seeds:
-            ctl = dense_lm.first_steps(dims, opt, frozen, train, cap.batches,
-                                       rows, precision="fp8")
+            ctl = cell.reference.first_steps(dims, opt, frozen, train,
+                                             cap.batches, rows,
+                                             precision="fp8")
             record("control", seed, checks.step_numbers(ctl, ref))
             half = program_first_steps(system, cell.traffic, seed,
                                        half_batch=True)

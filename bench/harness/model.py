@@ -1,6 +1,7 @@
 """The system under test, built from a configuration file and a traffic mix:
-the program's model config, the weights (made here, from the seed), and the
-jitted train step the window drives.
+the program's model config (from the configuration's family module,
+``bench/families/<ref>.py``), the weights (made here, from the seed), and
+the jitted train step the window drives.
 
 Weights are the benchmark's own: one jitted call draws every leaf from the
 seed, so the plain reference can draw the same ones without taking anything
@@ -10,8 +11,6 @@ loss and AdamW applied to the trainable subtree (the program has no frozen-
 parameter step of its own).
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
@@ -29,60 +28,14 @@ def seed_key(seed: int):
                               seed >> 32)
 
 
-def hf_dims(c: dict) -> dict:
-    """Sizes of a dense decoder under either naming a configuration file
-    uses (GPT-2's ``n_*`` keys or the Llama-style ``*_size`` keys)."""
-    d = c.get("hidden_size", c.get("n_embd"))
-    heads = c.get("num_attention_heads", c.get("n_head"))
-    ff = c.get("intermediate_size", c.get("n_inner")) or 4 * d
-    act = c.get("hidden_act", c.get("activation_function"))
-    return {
-        "d_model": d,
-        "num_layers": c.get("num_hidden_layers", c.get("n_layer")),
-        "num_heads": heads,
-        "num_kv_heads": c.get("num_key_value_heads", heads),
-        "head_dim": c.get("head_dim") or d // heads,
-        "d_ff": ff,
-        "vocab_size": c["vocab_size"],
-        "gated": act in ("silu", "swiglu"),
-        "rope_theta": float(c.get("rope_theta", 10000.0)),
-        "norm_eps": float(c.get("rms_norm_eps", c.get("layer_norm_epsilon",
-                                                      1e-5))),
-        "tie": bool(c.get("tie_word_embeddings", True)),
-    }
-
-
-def program_config(c: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    d = hf_dims(c)
-    if not d["tie"]:
-        raise SystemExit("bench: only tied-embedding dense decoders so far")
-    return ModelConfig(
-        name=c["name"], family="dense", num_layers=d["num_layers"],
-        d_model=d["d_model"], num_heads=d["num_heads"],
-        num_kv_heads=d["num_kv_heads"], d_ff=d["d_ff"],
-        vocab_size=d["vocab_size"], head_dim=d["head_dim"],
-        ffn_activation="swiglu" if d["gated"] else "gelu",
-        rope_theta=d["rope_theta"], norm_eps=d["norm_eps"],
-        tie_embeddings=True)
-
-
 def param_shapes(cfg):
     from repro.models import build_model
     return build_model(cfg).param_shapes()
 
 
-def _fan_in(path: str, shape: tuple) -> int:
-    s = shape[1:] if "layers" in path else shape     # drop the stacked axis
-    if path.endswith(("['wq']", "['wk']", "['wv']")):
-        return s[0]                                  # [d, heads, head_dim]
-    return math.prod(s[:-1])
-
-
-def make_init(shapes):
+def make_init(shapes, fan_in):
     """``init(key) -> params``: norms 1, the embedding N(0, 0.02), every
-    projection N(0, 1/fan_in), in the leaves' stored dtype."""
+    projection N(0, 1/fan_in(path, shape)), in the leaves' stored dtype."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def init(key):
@@ -94,43 +47,12 @@ def make_init(shapes):
             if len(core) == 1:
                 leaf = jnp.ones(sd.shape, sd.dtype)
             else:
-                std = 0.02 if "embed" in p else _fan_in(p, sd.shape) ** -0.5
+                std = 0.02 if "embed" in p else fan_in(p, sd.shape) ** -0.5
                 leaf = (std * jax.random.normal(k, sd.shape, jnp.float32)
                         ).astype(sd.dtype)
             leaves.append(leaf)
         return jax.tree_util.tree_unflatten(treedef, leaves)
     return init
-
-
-# ------------------------------------------------------------ trainable --
-def split_trainable(params, top_layers):
-    """(frozen, trainable) halves of a dense decoder's params: with
-    ``top_layers`` None everything trains; else the embedding and the lower
-    layers freeze and the top ``top_layers`` layers and the final norm
-    train."""
-    if top_layers is None:
-        return {}, params
-    cut = lambda x: x[:-top_layers]                  # noqa: E731
-    top = lambda x: x[-top_layers:]                  # noqa: E731
-    frozen = {"embed": params["embed"],
-              "layers": jax.tree_util.tree_map(cut, params["layers"])}
-    train = {"ln_f": params["ln_f"],
-             "layers": jax.tree_util.tree_map(top, params["layers"])}
-    return frozen, train
-
-
-def merge_trainable(frozen, train):
-    if not frozen:
-        return train
-    cat = lambda a, b: jnp.concatenate([a, b])       # noqa: E731
-    return {"embed": frozen["embed"], "ln_f": train["ln_f"],
-            "layers": jax.tree_util.tree_map(cat, frozen["layers"],
-                                             train["layers"])}
-
-
-def top_layers(traffic: dict):
-    t = traffic.get("trainable", "all")
-    return None if t == "all" else int(t["top_layers"])
 
 
 class System:
@@ -140,25 +62,24 @@ class System:
     frozen leaves are the same buffers from step to step, so the record
     pass finds them unchanged, as a frozen backbone is."""
 
-    def __init__(self, cfg, traffic: dict):
+    def __init__(self, family, config: dict, traffic: dict):
         from repro.models import build_model
         from repro.train.optimizer import (AdamWState, adamw,
                                            clip_by_global_norm)
         from repro.train.schedule import warmup_cosine
         from repro.train.state import TrainState
-        self.cfg = cfg
-        self.top = top_layers(traffic)
-        shapes = param_shapes(cfg)
-        init_params = make_init(shapes)
+        self.cfg = cfg = family.program_config(config)
+        self.trainable = trainable = traffic.get("trainable", "all")
+        init_params = make_init(param_shapes(cfg), family.fan_in)
         model = build_model(cfg)
         sched = warmup_cosine(PEAK_LR, WARMUP, TOTAL_STEPS)
         opt_init, opt_update = adamw(sched, b1=B1, b2=B2, eps=ADAM_EPS,
                                      weight_decay=WEIGHT_DECAY,
                                      moment_dtype=cfg.moment_dtype)
-        top = self.top
 
         def init_state(key):
-            frozen, train = split_trainable(init_params(key), top)
+            frozen, train = family.split_trainable(init_params(key),
+                                                   trainable)
             opt = opt_init(train)
             rng = jax.random.key_data(jax.random.fold_in(key, 1))
             return {"frozen": frozen,
@@ -167,7 +88,7 @@ class System:
 
         def record_train_step(frozen, st, batch):
             def loss_fn(tp):
-                return model.loss(merge_trainable(frozen, tp), batch)
+                return model.loss(family.merge_trainable(frozen, tp), batch)
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(st.params)
             grads, gnorm = clip_by_global_norm(grads, GRAD_CLIP)
@@ -178,7 +99,7 @@ class System:
 
         self.init_state = jax.jit(init_state)
         self._jit_step = jax.jit(record_train_step)
-        if top is None:
+        if trainable == "all":
             # full training is the program's own step, under the same name
             from repro.train.step import build_train_step
             _, program_step = build_train_step(

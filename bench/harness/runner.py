@@ -60,8 +60,12 @@ def _device(jax, peak):
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, *, t_start: float,
-             fault=None, control: bool = False, rehearsal: bool = False):
+             fault=None, control: bool = False, rehearsal: bool = False,
+             run_dir=None):
     """Run ``cell`` once. Returns (result dict, check lines for stderr).
+
+    The run's store is ``run_dir``, by default ``<checkout>/.bench_runs/
+    <cell>``; it is removed before the function returns.
 
     ``rehearsal`` runs the same path off the chip (tests): no device metric
     is reported. ``fault`` (tests) replaces the window's step:
@@ -75,15 +79,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, t_start: float,
     from harness.peaks import peaks as peak_table
     from harness.record import CompileCounter, Recorder
     from harness.trace import find_xplane, reduce_trace, top, merged_gaps
-    from references import dense_lm
 
     compiles = CompileCounter()
-    dims = M.hf_dims(cell.config)
-    cfg = M.program_config(cell.config)
+    dims = cell.family.dims(cell.config)
     kind = jax.devices()[0].device_kind
     peaks = None if rehearsal else peak_table(kind)
-    system = M.System(cfg, cell.traffic)
-    run_dir = RUNS_DIR / cell.name
+    system = M.System(cell.family, cell.config, cell.traffic)
+    run_dir = RUNS_DIR / cell.name if run_dir is None else run_dir
     trace_dir = TRACE_DIR / cell.name
     shutil.rmtree(trace_dir, ignore_errors=True)
     t_setup = {}
@@ -136,10 +138,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, t_start: float,
            "eps": M.ADAM_EPS, "weight_decay": M.WEIGHT_DECAY,
            "grad_clip": M.GRAD_CLIP}
     rows = int(cell.traffic["reference_block_rows"])
-    ref = dense_lm.first_steps(dims, opt, frozen, train,
-                               rec.captured.batches, rows)
-    got = dense_lm.first_steps(dims, opt, frozen, train,
-                               rec.captured.batches, rows, precision="fp8") \
+    ref = cell.reference.first_steps(dims, opt, frozen, train,
+                                     rec.captured.batches, rows)
+    got = cell.reference.first_steps(dims, opt, frozen, train,
+                                     rec.captured.batches, rows,
+                                     precision="fp8") \
         if control else checks.program_readings(rec.captured)
     del frozen, train
     phases["reference_s"] = time.monotonic() - t0
@@ -164,12 +167,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, t_start: float,
                 result["metrics"][m["name"]] = {"value": values[m["name"]],
                                                 "unit": m["unit"]}
         else:
-            top_layers = M.top_layers(cell.traffic)
             leaves = [(sd.shape, sd.dtype) for sd in
                       jax.tree_util.tree_leaves(system.state_shapes)]
             view = RunView(
                 cell, rec, summary, peaks,
-                work.step_flops(dims, rec.batch, rec.seq, top_layers),
+                cell.family.step_flops(dims, rec.batch, rec.seq,
+                                       system.trainable),
                 work.fingerprint_bytes(leaves), leaves,
                 M.STEP_NAME)
             for m in cell.per_layer:

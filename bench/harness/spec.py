@@ -3,8 +3,11 @@
 A configuration is ``configs[].file``; a traffic mix is
 ``bench/traffic/<traffic>.json``; a cell's correctness limits are
 ``bench/limits/<workload>.json``; a per-layer metric's reader is
-``bench/metrics/<metric>.py``. Adding a configuration, a mix, a cell or a
-metric adds files and entries and edits none.
+``bench/metrics/<metric>.py``. A configuration's ``reference`` key, ``<ref>``,
+names its model family: ``bench/families/<ref>.py`` builds the program's
+model (its docstring gives the interface) and ``bench/references/<ref>.py``
+is the plain reference. Adding a configuration, a model family, a mix, a
+cell or a metric adds files and entries and edits none.
 """
 from __future__ import annotations
 
@@ -21,8 +24,8 @@ def load_benchmark() -> dict:
         return json.load(f)
 
 
-# every key a traffic mix may set: `why` says what it is for, and the
-# generator reads the others
+# every key a traffic mix may set, beyond those its cell's family reads:
+# `why` says what it is for, and the generator reads the others
 TRAFFIC_KEYS = {"why", "batch", "seq", "steps_per_ckpt", "trainable",
                 "await_full", "max_warmup_intervals", "reference_block_rows"}
 
@@ -32,23 +35,48 @@ def _read_json(path: Path) -> dict:
         return json.load(f)
 
 
-def read_traffic(path: Path) -> dict:
-    """A traffic mix, refused where it sets a key the generator does not
-    read."""
-    traffic = _read_json(path)
-    unknown = set(traffic) - TRAFFIC_KEYS
+def load_module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded by its path."""
+    path = BENCH / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_parts(config: dict):
+    """(family, reference): the modules that the configuration's
+    ``reference`` key names. A configuration that names none, or a name
+    without both files, is refused before either is loaded."""
+    ref = config.get("reference")
+    known = sorted(p.stem for p in (BENCH / "families").glob("*.py")
+                   if (BENCH / "references" / p.name).is_file())
+    if ref not in known:
+        raise SystemExit(f"bench: configuration {config.get('name')!r} names "
+                         f"the reference {ref!r}; known: {known}")
+    return load_module("families", ref), load_module("references", ref)
+
+
+def check_traffic(name: str, traffic: dict, family) -> None:
+    """Refuse a traffic mix that sets a key neither the generator nor the
+    cell's family reads."""
+    unknown = (set(traffic) - TRAFFIC_KEYS
+               - set(getattr(family, "TRAFFIC_KEYS", ())))
     if unknown:
-        raise SystemExit(f"bench: {path.name} sets {sorted(unknown)}, which "
-                         f"no part of the benchmark reads")
-    return traffic
+        raise SystemExit(f"bench: the traffic mix of {name} sets "
+                         f"{sorted(unknown)}, which no part of the benchmark "
+                         f"reads")
 
 
 class Cell:
-    """One workload of BENCHMARK.json with its configuration, traffic mix,
-    limits and metric entries resolved."""
+    """One workload of BENCHMARK.json with its configuration, model family,
+    plain reference, traffic mix, limits and metric entries resolved."""
 
     def __init__(self, name, config, traffic, limits, chips, end_to_end,
                  per_layer):
+        self.family, self.reference = model_parts(config)
+        check_traffic(name, traffic, self.family)
         self.name = name
         self.config = config
         self.traffic = traffic
@@ -69,7 +97,7 @@ class Cell:
         layer = [m for m in bench["per_layer"] if _applies(m, name)
                  and any(e["name"] == m["moves"] for e in e2e)]
         return cls(name, _read_json(ROOT / entry["file"]),
-                   read_traffic(BENCH / "traffic" / f"{wl['traffic']}.json"),
+                   _read_json(BENCH / "traffic" / f"{wl['traffic']}.json"),
                    _read_json(BENCH / "limits" / f"{name}.json"),
                    wl["chips"], e2e, layer)
 
@@ -80,9 +108,4 @@ def _applies(metric: dict, workload: str) -> bool:
 
 def load_reader(metric_name: str):
     """The ``read(run)`` function of ``bench/metrics/<metric_name>.py``."""
-    path = BENCH / "metrics" / f"{metric_name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric_name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", metric_name).read

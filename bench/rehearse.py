@@ -45,7 +45,7 @@ def rehearse(cell, device) -> list:
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
             tree)
 
-    system = model.System(model.program_config(cell.config), cell.traffic)
+    system = model.System(cell.family, cell.config, cell.traffic)
     b, s = int(cell.traffic["batch"]), int(cell.traffic["seq"])
     batch = {"tokens": jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one)}
     out = []

@@ -16,10 +16,10 @@ def test_control_fails_and_program_passes():
         assert r["correct"] is (r["kind"] == "program"), r
 
 
-def test_control_in_the_run_is_not_correct():
+def test_control_in_the_run_is_not_correct(tmp_path):
     res, lines = run_cell(tiny_cell(), 47, 0.3, False,
                           t_start=time.monotonic(), control=True,
-                          rehearsal=True)
+                          rehearsal=True, run_dir=tmp_path)
     assert res["correct"] is False
     assert res["checks"]["store_mismatch"]["value"] == 0
     assert any(line.endswith("FAILED") for line in lines)

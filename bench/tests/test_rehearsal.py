@@ -15,9 +15,11 @@ from harness.spec import BENCH, ROOT
 
 
 @pytest.mark.parametrize("trainable", ["all", {"top_layers": 1}])
-def test_rehearsal_is_correct_and_reports_no_device_metric(trainable):
+def test_rehearsal_is_correct_and_reports_no_device_metric(trainable,
+                                                           tmp_path):
     res, lines = run_cell(tiny_cell(trainable), 2**31 + 77, 0.5, False,
-                          t_start=time.monotonic(), rehearsal=True)
+                          t_start=time.monotonic(), rehearsal=True,
+                          run_dir=tmp_path)
     assert res["correct"] is True
     assert res["metrics"] == {}
     assert res["device"]["platform"] == "cpu"
@@ -33,10 +35,11 @@ def test_rehearsal_is_correct_and_reports_no_device_metric(trainable):
     assert len(lines) == 6 and all(line.endswith("ok") for line in lines)
 
 
-def test_warmup_that_ends_unsteady_is_not_correct():
+def test_warmup_that_ends_unsteady_is_not_correct(tmp_path):
     # one warm-up interval submits the full checkpoint and no delta
     res, lines = run_cell(tiny_cell(max_warmup=1), 2**31 + 79, 0.3, False,
-                          t_start=time.monotonic(), rehearsal=True)
+                          t_start=time.monotonic(), rehearsal=True,
+                          run_dir=tmp_path)
     assert res["correct"] is False
     assert res["checks"]["warmup_unsteady"]["value"] == 1
     assert any(line.startswith("check warmup_unsteady")

@@ -3,7 +3,9 @@ import ml_dtypes
 import numpy as np
 
 from harness import work
-from harness.model import hf_dims
+from harness.spec import load_module
+
+dense = load_module("families", "dense_lm")
 
 # one layer, d 4, 2 heads of 2, 1 kv head, gated d_ff 8, vocab 10
 DIMS = {"d_model": 4, "num_layers": 1, "num_heads": 2, "num_kv_heads": 1,
@@ -18,7 +20,7 @@ def test_full_training_flops_by_hand():
     layer = 2 * (proj + mlp) * tokens
     attn = 4 * 2 * 2 * (s + 1) / 2 * tokens
     logits = 2 * 4 * 10 * preds
-    assert work.step_flops(DIMS, b, s) == 3 * (layer + attn + logits)
+    assert dense.step_flops(DIMS, b, s, "all") == 3 * (layer + attn + logits)
 
 
 def test_frozen_training_flops_by_hand():
@@ -31,15 +33,16 @@ def test_frozen_training_flops_by_hand():
     fwd = 3 * (layer + attn) + logits
     qkv_in = 2 * 4 * (2 + 2) * 2 * tokens
     bwd = logits + 2 * (layer + attn) - qkv_in
-    assert work.step_flops(d, b, s, top_layers=1) == fwd + bwd
-    assert work.step_flops(d, b, s, top_layers=1) < work.step_flops(d, b, s)
+    top = {"top_layers": 1}
+    assert dense.step_flops(d, b, s, top) == fwd + bwd
+    assert dense.step_flops(d, b, s, top) < dense.step_flops(d, b, s, "all")
 
 
 def test_florbench_step_is_about_23_tflop():
     import json
     from harness.spec import BENCH
     cfg = json.loads((BENCH / "configs" / "florbench-100m.json").read_text())
-    f = work.step_flops(hf_dims(cfg), 32, 1024)
+    f = dense.step_flops(dense.dims(cfg), 32, 1024, "all")
     # 6 x 85.0M x 32768 + logits 4.94e12 + causal attention 1.86e12
     assert 23.4e12 < f < 23.6e12
 

@@ -7,11 +7,12 @@ TINY_CONFIG = {
     "num_attention_heads": 4, "num_key_value_heads": 2,
     "num_hidden_layers": 3, "vocab_size": 300, "hidden_act": "silu",
     "rms_norm_eps": 1e-5, "rope_theta": 10000.0, "tie_word_embeddings": True,
+    "reference": "dense_lm",
 }
 
 
 def tiny_cell(trainable="all", steps_per_ckpt=2, limits=None,
-              max_warmup=5):
+              max_warmup=5, config=TINY_CONFIG):
     # awaiting the full checkpoint makes the warm-up steady however fast
     # this machine's writer runs beside the tiny steps
     traffic = {"batch": 4, "seq": 32, "steps_per_ckpt": steps_per_ckpt,
@@ -26,4 +27,4 @@ def tiny_cell(trainable="all", steps_per_ckpt=2, limits=None,
     e2e = [{"name": "record_tokens_per_s", "unit": "tokens/s"},
            {"name": "stored_mb_per_ckpt", "unit": "MB"},
            {"name": "setup_s", "unit": "s"}]
-    return Cell("tiny.record", TINY_CONFIG, traffic, limits, 1, e2e, [])
+    return Cell("tiny.record", config, traffic, limits, 1, e2e, [])
