@@ -32,8 +32,9 @@ ARCHS = [
     "llava-next-mistral-7b",
 ]
 
-# extra (non-assigned) configs: the paper-scale end-to-end example model
-EXTRA = ["florbench-100m"]
+# extra (non-assigned) configs: the paper-scale end-to-end example model and
+# the MoE the benchmark records an expert-specialised fine-tune of
+EXTRA = ["florbench-100m", "moonlight-16b-a3b"]
 
 
 def _module(name: str):

@@ -19,14 +19,26 @@ class MoEConfig:
     num_shared_experts: int = 0
     first_dense_layers: int = 0          # leading layers that stay dense
     router: str = "softmax"              # softmax | sigmoid (deepseek-v3)
-    capacity_factor: float = 1.25
     router_aux_loss: float = 0.0         # load-balance loss coefficient
+    selection_bias: bool = False         # a per-expert bias added to the
+    #   sigmoid scores for top-k selection only (DeepSeek-V3 "noaux_tc")
+    routed_scaling: float = 1.0          # gate multiplier after normalising
+    # the share of the routed experts this model holds (expert parallelism):
+    # experts [expert_offset, expert_offset + experts_held) of num_experts;
+    # the router stays num_experts wide. None holds them all.
+    expert_offset: int = 0
+    experts_held: Optional[int] = None
+
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek Multi-head Latent Attention."""
-    q_lora_rank: int
+    """DeepSeek Multi-head Latent Attention. ``q_lora_rank`` None projects
+    the queries directly (no query latent)."""
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -126,11 +138,13 @@ class ModelConfig:
         if self.mla is not None:
             m = self.mla
             qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
-            return (d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk_hd
-                    + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+            q = (d * self.num_heads * qk_hd if m.q_lora_rank is None else
+                 d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk_hd
+                 + m.q_lora_rank)                          # + its norm
+            return (q + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     + m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
                     + self.num_heads * m.v_head_dim * d
-                    + m.q_lora_rank + m.kv_lora_rank)      # latent norms
+                    + m.kv_lora_rank)                      # latent norm
         return d * (self.num_heads + 2 * self.num_kv_heads) * hd + self.num_heads * hd * d
 
     def _mlp_params(self, d_ff: int) -> int:
@@ -175,9 +189,9 @@ class ModelConfig:
             mo = self.moe
             dense_l = mo.first_dense_layers
             moe_l = L - dense_l
-            router = d * mo.num_experts
+            router = d * mo.num_experts + mo.num_experts * mo.selection_bias
             per_moe = (attn + router
-                       + (mo.num_experts + mo.num_shared_experts)
+                       + (mo.held() + mo.num_shared_experts)
                        * self._mlp_params(mo.d_ff_expert))
             layers = dense_l * (attn + self._mlp_params(self.d_ff)) + moe_l * per_moe
         else:
@@ -190,7 +204,9 @@ class ModelConfig:
         return emb + layers + dec + norms
 
     def active_param_count(self) -> int:
-        """Activated params per token (MoE counts top_k + shared experts only)."""
+        """Activated params per token (MoE counts top_k + shared experts
+        only; a model that holds a share of the experts counts the whole
+        layer's top_k)."""
         if self.moe is None:
             return self.param_count()
         mo = self.moe
@@ -198,7 +214,7 @@ class ModelConfig:
         gated = 3 if self.ffn_activation in ("swiglu", "geglu") else 2
         per_expert = self.d_model * mo.d_ff_expert * gated
         moe_l = self.num_layers - mo.first_dense_layers
-        inactive = moe_l * (mo.num_experts - mo.top_k) * per_expert
+        inactive = moe_l * (mo.held() - mo.top_k) * per_expert
         return full - inactive
 
 

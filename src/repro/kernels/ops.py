@@ -16,12 +16,13 @@ from repro.kernels.chunk_delta import (changed_mask_pallas,
                                        fingerprint_changed_pallas,
                                        fingerprint_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.gmm import expert_gmm, expert_tgmm
 from repro.kernels.quantize import (Q4_BLOCK, Q8_BLOCK, dequantize_pallas,
                                     gather_quantize4_pallas,
                                     gather_quantize_pallas, quantize_pallas)
 from repro.kernels.ref import (changed_mask_ref, fingerprint_changed_ref,
                                fingerprint_ref, gather_quantize4_ref,
-                               gather_quantize_ref)
+                               gather_quantize_ref, gmm_ref)
 
 CHUNK_WORDS = 1024        # 4 KiB chunks (uint32 words)
 
@@ -284,3 +285,44 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
     return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
                                   block_q=block_q, block_k=block_k,
                                   interpret=_interpret())
+
+
+# ------------------------------------------------------ expert products ---
+
+def expert_matmul(lhs, rhs, tile_group, num_tiles, tm: int):
+    """Grouped product of a tile-aligned expert layout (``kernels/gmm.py``):
+    ``out[r] = lhs[r] @ rhs[g(r)]``, differentiable in ``lhs`` and ``rhs``.
+    On TPU the forward and the input gradient are ``expert_gmm`` and the
+    weight gradient ``expert_tgmm``; on CPU the jnp oracle, differentiated
+    by JAX."""
+    if _interpret():
+        return gmm_ref(lhs, rhs, tile_group, num_tiles, tm)
+    return expert_matmul_pallas(lhs, rhs, tile_group, num_tiles, tm, False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def expert_matmul_pallas(lhs, rhs, tile_group, num_tiles, tm, interpret):
+    return expert_gmm(lhs, rhs, tile_group, num_tiles, tm=tm,
+                      interpret=interpret)
+
+
+def _expert_matmul_fwd(lhs, rhs, tile_group, num_tiles, tm, interpret):
+    out = expert_matmul_pallas(lhs, rhs, tile_group, num_tiles, tm,
+                               interpret)
+    return out, (lhs, rhs, tile_group, num_tiles)
+
+
+def _expert_matmul_bwd(tm, interpret, res, g):
+    lhs, rhs, tile_group, num_tiles = res
+    G = rhs.shape[0]
+    dlhs = expert_gmm(g, rhs, tile_group, num_tiles, tm=tm,
+                      transpose_rhs=True, interpret=interpret)
+    active = jnp.arange(tile_group.shape[0]) < num_tiles
+    tiles = jnp.zeros((G,), jnp.int32).at[tile_group].add(
+        active.astype(jnp.int32))
+    drhs = expert_tgmm(lhs, g, tile_group, num_tiles, tiles, num_groups=G,
+                       tm=tm, interpret=interpret)
+    return dlhs, drhs.astype(rhs.dtype), None, None
+
+
+expert_matmul_pallas.defvjp(_expert_matmul_fwd, _expert_matmul_bwd)

@@ -85,3 +85,34 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, scale=None):
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bksd->bkgqd", w, v.astype(jnp.float32))
     return o.reshape(B, H, Sq, d).astype(q.dtype)
+
+
+def _row_groups(tile_group, num_tiles, tm: int, rows: int):
+    """Group of each row of a tile-aligned expert layout, -1 past the last
+    tile that holds rows."""
+    g = jnp.repeat(tile_group, tm, total_repeat_length=rows)
+    return jnp.where(jnp.arange(rows) < num_tiles * tm, g, -1)
+
+
+def gmm_ref(lhs, rhs, tile_group, num_tiles, tm: int,
+            transpose_rhs: bool = False):
+    """Grouped-matmul oracle: ``out[r] = lhs[r] @ rhs[g(r)]`` (``.T`` with
+    ``transpose_rhs``) for rows in the first ``num_tiles`` tiles, 0 after;
+    one masked product per group."""
+    g = _row_groups(tile_group, num_tiles, tm, lhs.shape[0])
+    spec = "mk,nk->mn" if transpose_rhs else "mk,kn->mn"
+    out = jnp.zeros((lhs.shape[0], rhs.shape[1] if transpose_rhs
+                     else rhs.shape[2]), jnp.float32)
+    for e in range(rhs.shape[0]):
+        part = jnp.einsum(spec, lhs, rhs[e], preferred_element_type=jnp.float32)
+        out = out + jnp.where((g == e)[:, None], part, 0.0)
+    return out.astype(lhs.dtype)
+
+
+def tgmm_ref(lhs, dout, tile_group, num_tiles, tm: int, num_groups: int):
+    """Weight-gradient oracle: ``out[e] = lhs[rows of e].T @ dout[rows of
+    e]``, [num_groups, K, N]."""
+    g = _row_groups(tile_group, num_tiles, tm, lhs.shape[0])
+    hot = (g[:, None] == jnp.arange(num_groups)[None, :]).astype(jnp.float32)
+    return jnp.einsum("mk,me,mn->ekn", lhs.astype(jnp.float32), hot,
+                      dout.astype(jnp.float32)).astype(lhs.dtype)

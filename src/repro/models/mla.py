@@ -22,11 +22,15 @@ def mla_spec(cfg):
     d, H = cfg.d_model, cfg.num_heads
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
     hax = "heads" if cfg.dense_layout == "tp" else None
+    if m.q_lora_rank is None:                     # direct query projection
+        q = {"w_q": dense_spec((d, H, qk_hd), ("embed", hax, None), fan_in=d)}
+    else:
+        q = {"w_dq": dense_spec((d, m.q_lora_rank), ("embed", None)),
+             "q_ln": ParamSpec((m.q_lora_rank,), (None,), init="ones"),
+             "w_uq": dense_spec((m.q_lora_rank, H, qk_hd), (None, hax, None),
+                                fan_in=m.q_lora_rank)}
     return {
-        "w_dq": dense_spec((d, m.q_lora_rank), ("embed", None)),
-        "q_ln": ParamSpec((m.q_lora_rank,), (None,), init="ones"),
-        "w_uq": dense_spec((m.q_lora_rank, H, qk_hd), (None, hax, None),
-                           fan_in=m.q_lora_rank),
+        **q,
         "w_dkv": dense_spec((d, m.kv_lora_rank), ("embed", None)),
         "kv_ln": ParamSpec((m.kv_lora_rank,), (None,), init="ones"),
         "w_kr": dense_spec((d, m.qk_rope_head_dim), ("embed", None)),
@@ -42,9 +46,12 @@ def mla_spec(cfg):
 def _latents(cfg, p, x, positions, rope=None):
     """Shared q / kv latent computation. Returns (q_nope, q_rope, c_kv, k_r)."""
     m = cfg.mla
-    cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(x.dtype)),
-                  p["q_ln"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rnh->bsnh", cq, p["w_uq"].astype(x.dtype))
+    if "w_q" in p:
+        q = jnp.einsum("bsd,dnh->bsnh", x, p["w_q"].astype(x.dtype))
+    else:
+        cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(x.dtype)),
+                      p["q_ln"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rnh->bsnh", cq, p["w_uq"].astype(x.dtype))
     q_nope = q[..., : m.qk_nope_head_dim]
     q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions[:, :, None],
                         cfg.rope_theta, tables=rope)

@@ -177,9 +177,11 @@ def lm_forward(cfg, params, tokens=None, embeds=None):
             x, _ = jax.lax.scan(dbody, x, params["dense_layers"])
         def mbody(h, lyr):
             h2, m = moe_block(cfg, lyr, h, positions, window, rope)
-            return h2, (m["moe_aux"], m["moe_dropped"])
-        x, (aux, drop) = jax.lax.scan(_remat(cfg, mbody), x, params["layers"])
-        metrics = {"moe_aux": aux.mean(), "moe_dropped": drop.mean()}
+            return h2, (m["moe_aux"], m["moe_rows"], m["moe_load_max"])
+        x, (aux, rows, load) = jax.lax.scan(_remat(cfg, mbody), x,
+                                            params["layers"])
+        metrics = {"moe_aux": aux.mean(), "moe_rows": rows.sum(),
+                   "moe_load_max": load.max()}
     elif fam == "ssm":
         fwd = _mamba_fwd(cfg)
         body = _remat(cfg, lambda h, lyr: (h + fwd(cfg, lyr, h), None))

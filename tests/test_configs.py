@@ -14,6 +14,7 @@ PUBLISHED = {
     "mixtral-8x7b": (46.7e9, 12.9e9),
     "seamless-m4t-large-v2": (1.6e9, 1.6e9),
     "llava-next-mistral-7b": (7.2e9, 7.2e9),
+    "moonlight-16b-a3b": (16e9, 3e9),
 }
 
 

@@ -23,7 +23,10 @@ FAMILIES = [
     ("hybrid", "zamba2-7b"),
     ("audio", "seamless-m4t-large-v2"),
     ("vlm", "llava-next-mistral-7b"),
+    ("moe", "moonlight-16b-a3b"),
 ]
+# the second MoE is one chip's share of an expert-parallel layer
+IDS = {"moonlight-16b-a3b": "moe-share"}
 
 
 def _loop(sess, cfg, init_state, ts, probe=False):
@@ -50,7 +53,7 @@ def _leaves_equal(a, b):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("family,arch", FAMILIES,
-                         ids=[f for f, _ in FAMILIES])
+                         ids=[IDS.get(a, f) for f, a in FAMILIES])
 def test_family_record_replay_bit_identical(tmp_path, family, arch):
     cfg = C.get_smoke(arch)
     assert cfg.family == family

@@ -13,12 +13,6 @@ from repro.models import build_model
 from repro.train.step import build_train_step
 
 
-def _high_cf(cfg):
-    if cfg.moe is None:
-        return cfg
-    return cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-
-
 @pytest.mark.parametrize("arch", C.ARCHS + C.EXTRA)
 def test_smoke_forward_one_train_step(arch):
     """Assigned-arch requirement: reduced config, one train step on CPU,
@@ -44,7 +38,7 @@ def test_smoke_forward_one_train_step(arch):
                                   "zamba2-7b", "mixtral-8x7b"])
 def test_causality(arch):
     """Perturbing a future token must not change past logits."""
-    cfg = _high_cf(C.get_smoke(arch)).replace(
+    cfg = C.get_smoke(arch).replace(
         attention_impl="naive", dtype="float32", param_dtype="float32")
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
@@ -90,7 +84,7 @@ def test_gqa_equals_mha_when_kv_equals_heads():
 @pytest.mark.parametrize("arch", ["granite-3-2b", "gemma-2b", "qwen3-14b",
                                   "mixtral-8x7b", "deepseek-v3-671b"])
 def test_chunked_equals_naive_attention(arch):
-    cfg_n = _high_cf(C.get_smoke(arch)).replace(
+    cfg_n = C.get_smoke(arch).replace(
         attention_impl="naive", dtype="float32", param_dtype="float32")
     cfg_c = cfg_n.replace(attention_impl="chunked", attention_chunk=16)
     mn, mc = build_model(cfg_n), build_model(cfg_c)
@@ -107,7 +101,7 @@ def test_chunked_equals_naive_attention(arch):
                                   "llava-next-mistral-7b"])
 def test_decode_matches_prefill(arch):
     """Greedy continuation invariance: decode(prefill(x), t) == prefill(x+t)."""
-    cfg = _high_cf(C.get_smoke(arch)).replace(
+    cfg = C.get_smoke(arch).replace(
         attention_impl="naive", dtype="float32", param_dtype="float32")
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(1))
@@ -176,18 +170,48 @@ def test_moe_routing_properties():
     assert float(aux) >= 1.0 - 1e-6   # Switch aux loss lower bound at balance
 
 
-def test_moe_capacity_drop_metric():
-    from repro.models.moe import _moe_local
-    cfg = C.get_smoke("mixtral-8x7b").replace(dtype="float32",
-                                              param_dtype="float32")
-    m = build_model(cfg)
-    params = m.init(jax.random.PRNGKey(1))
+def test_moe_skewed_router_drops_nothing():
+    """Dropless: a router skewed so that one held expert takes most rows
+    still computes every pair routed to a held expert (``moe_rows``), and
+    the layer's output equals every held expert applied densely."""
+    from repro.models.moe import moe_apply
+    cfg = C.get_smoke("moonlight-16b-a3b").replace(dtype="float32",
+                                                   param_dtype="float32")
+    mo = cfg.moe
+    params = build_model(cfg).init(jax.random.PRNGKey(1))
     p = jax.tree_util.tree_map(lambda x: x[0], params["layers"])["moe"]
-    x = jax.random.normal(jax.random.PRNGKey(2), (32, cfg.d_model)) * 0.1
-    _, _, drop_hi = _moe_local(cfg, p, x, 0, 4, capacity=64)
-    _, _, drop_lo = _moe_local(cfg, p, x, 0, 4, capacity=4)
-    assert float(drop_hi) == 0.0
-    assert float(drop_lo) > 0.0
+    hot = mo.expert_offset + 2                   # a held expert
+    p["router_bias"] = p["router_bias"].at[hot].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 40, cfg.d_model))
+    y, m = jax.jit(lambda p, x: moe_apply(cfg, p, x))(p, x)
+
+    xf = x.reshape(-1, cfg.d_model)
+    s = jax.nn.sigmoid(xf @ p["router"])
+    _, ids = jax.lax.top_k(s + p["router_bias"], mo.top_k)
+    ids = np.asarray(ids)
+    local = ids - mo.expert_offset
+    held = (local >= 0) & (local < mo.held())
+    rows = np.bincount(local[held], minlength=mo.held())
+    assert (ids == hot).sum() == xf.shape[0]     # every token picks it
+    assert rows[2] == xf.shape[0] and rows.max() > 3 * rows.mean()
+    assert float(m["moe_rows"]) == held.sum()
+    np.testing.assert_allclose(float(m["moe_load_max"]),
+                               rows.max() / rows.mean(), rtol=1e-6)
+
+    w = np.asarray(jnp.take_along_axis(s, jnp.asarray(ids), -1))
+    w = w / w.sum(-1, keepdims=True) * mo.routed_scaling
+    e = p["experts"]
+    dense = jnp.einsum("td,edf->tef", xf, e["wi"])
+    act = jax.nn.silu(jnp.einsum("td,edf->tef", xf, e["wg"])) * dense
+    out_e = jnp.einsum("tef,efd->ted", act, e["wo"])       # [T, held, d]
+    gate = np.zeros((xf.shape[0], mo.held()), np.float32)
+    for t, j in zip(*np.nonzero(held)):
+        gate[t, local[t, j]] += w[t, j]
+    sh = p["shared"]
+    shared = (jax.nn.silu(xf @ sh["wg"]) * (xf @ sh["wi"])) @ sh["wo"]
+    ref = jnp.einsum("te,ted->td", gate, out_e) + shared
+    np.testing.assert_allclose(np.asarray(y).reshape(ref.shape),
+                               np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
 def test_vlm_loss_masks_image_prefix():
@@ -216,7 +240,7 @@ def test_seq_shard_loss_invariance():
 
 def test_dense_layout_dp_loss_invariance():
     """dense_layout only changes sharding axes, never math."""
-    cfg = _high_cf(C.get_smoke("deepseek-v3-671b")).replace(
+    cfg = C.get_smoke("deepseek-v3-671b").replace(
         dtype="float32", param_dtype="float32")
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))

@@ -127,3 +127,32 @@ def test_raw_gather_program_is_named():
     idx = jax.ShapeDtypeStruct((8,), jnp.int32)
     text = ops.gather_changed_rows.lower(x, idx, chunk_words=1024).as_text()
     assert "@jit_gather_changed_rows" in text
+
+
+# Moonlight-16B-A3B's expert products on one chip's share: 8 held experts,
+# batch 2 x 8192 tokens x top-6 pairs in 256-row tiles
+EXPERT_ROWS, EXPERT_TILE, EXPERTS_HELD = 100352, 256, 8
+EXPERT_WEIGHTS = {"wi": (2048, 1408), "wo": (1408, 2048)}
+
+
+@pytest.mark.parametrize("weight", sorted(EXPERT_WEIGHTS))
+def test_expert_products_compile_and_carry_stable_names(one_chip, weight):
+    K, N = EXPERT_WEIGHTS[weight]
+    tiles = EXPERT_ROWS // EXPERT_TILE
+    lhs = jax.ShapeDtypeStruct((EXPERT_ROWS, K), jnp.bfloat16,
+                               sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((EXPERTS_HELD, K, N), jnp.bfloat16,
+                               sharding=one_chip)
+    tg = jax.ShapeDtypeStruct((tiles,), jnp.int32, sharding=one_chip)
+    nt = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(lhs, rhs, tg, nt):
+        def f(a, w):
+            y = ops.expert_matmul_pallas(a, w, tg, nt, EXPERT_TILE, False)
+            return jnp.sum(y.astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1))(lhs, rhs)
+    text = _compile_text(fwd_bwd, lhs, rhs, tg, nt)
+    calls = sorted({ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                    for ln in text.splitlines()
+                    if "custom_call_target=\"tpu_custom_call\"" in ln})
+    assert calls == ["expert_gmm", "expert_tgmm"], calls
