@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.kernels.chunk_delta import (changed_mask_pallas,
                                        fingerprint_changed_pallas,
@@ -326,3 +327,46 @@ def _expert_matmul_bwd(tm, interpret, res, g):
 
 
 expert_matmul_pallas.defvjp(_expert_matmul_fwd, _expert_matmul_bwd)
+
+
+# ----------------------------------------------------- causal attention ---
+
+# q and kv block of the splash kernels, forward and backward: the first that
+# divides the sequence. On a TPU v5e at B 2, H 16, S 8192, qk 192, v 128 in
+# bfloat16, larger blocks ran faster in the forward and in both backward
+# kernels (forward 10.0 ms at 512, 20.0 at 256, 59.8 at 128)
+ATTENTION_BLOCKS = (512, 256, 128)
+
+
+def attention_block(seq: int):
+    """The square block ``causal_attention`` tiles a sequence of ``seq``
+    into, forward and backward; None where no candidate divides it."""
+    return next((b for b in ATTENTION_BLOCKS if seq % b == 0), None)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_causal(heads: int, seq: int, block: int, interpret: bool):
+    """The splash MHA kernel over ``heads`` causal [seq, seq] masks. Its mask
+    tables are built in numpy; evaluated eagerly, so that a kernel first
+    made inside a trace holds no tracer."""
+    mask = splash.MultiHeadMask([splash.CausalMask((seq, seq))] * heads)
+    blocks = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret)
+
+
+def causal_attention(q, k, v, *, block=None):
+    """Causal softmax attention at scale ``1/sqrt(dqk)``: q, k [B, H, S, dqk]
+    and v [B, H, S, dv] give [B, H, S, dv]. The splash kernels (forward, dq,
+    dkv under their own VJP) skip the wholly masked blocks; the forward's
+    softmax statistics and PV are float32, and QK^T accumulates in float32
+    from the inputs' dtype. ``block`` defaults to ``attention_block(S)``."""
+    B, H, S, dqk = q.shape
+    kernel = _splash_causal(H, S, block or attention_block(S), _interpret())
+    # the kernel takes no scale: fold it into q, rounded once from float32
+    q = (q.astype(jnp.float32) / np.sqrt(dqk)).astype(q.dtype)
+    return jax.vmap(kernel)(q, k, v)

@@ -1,9 +1,11 @@
 """DeepSeek-V3 Multi-head Latent Attention.
 
-Train path expands the latent to per-head K/V and reuses the generic chunked
-softmax. Decode uses the ABSORBED form: the cache holds only the compressed
-latent c_kv [B,S,r_kv] + shared rope key k_r [B,S,r_rope] — the paper-relevant
-KV-compression trick — and W_uk/W_uv are absorbed into the query/output.
+Train path expands the latent to per-head K/V: on one TPU it runs the causal
+splash kernels (``kernels/ops.py`` ``causal_attention``), elsewhere the
+generic chunked softmax. Decode uses the ABSORBED form: the cache holds only
+the compressed latent c_kv [B,S,r_kv] + shared rope key k_r [B,S,r_rope] —
+the paper-relevant KV-compression trick — and W_uk/W_uv are absorbed into the
+query/output.
 """
 from __future__ import annotations
 
@@ -11,10 +13,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models.attention import _chunked_sdpa, _mask, NEG_INF
 from repro.models.layers import apply_rope, dense_spec, rms_norm
 from repro.models.params import ParamSpec
-from repro.parallel import constrain
+from repro.parallel import constrain, current_mesh
 
 
 def mla_spec(cfg):
@@ -62,8 +65,18 @@ def _latents(cfg, p, x, positions, rope=None):
     return q_nope, q_rope, c_kv, k_r
 
 
+def _flash(cfg, seq: int) -> bool:
+    """Whether the training attention runs ``ops.causal_attention``: on TPU,
+    at a sequence its blocks divide, unsharded, under no mesh of more than
+    one device."""
+    mesh = current_mesh()
+    return (not ops._interpret() and ops.attention_block(seq) is not None
+            and not cfg.seq_shard and (mesh is None or mesh.size == 1))
+
+
 def mla_attention(cfg, p, x, positions, rope=None):
-    """Training/prefill forward (expanded form + chunked softmax)."""
+    """Training/prefill forward (expanded form + causal flash kernel or
+    chunked softmax)."""
     m = cfg.mla
     H = cfg.num_heads
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
@@ -72,6 +85,14 @@ def mla_attention(cfg, p, x, positions, rope=None):
     k_nope = jnp.einsum("bsr,rnh->bsnh", c_kv, p["w_uk"].astype(x.dtype))
     v = jnp.einsum("bsr,rnh->bsnh", c_kv, p["w_uv"].astype(x.dtype))
     B, S = x.shape[:2]
+    if _flash(cfg, S):
+        # heads-major for the kernel; v keeps its own head dim
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r[:, :, None, :],
+                                      (B, S, H, m.qk_rope_head_dim))], axis=-1)
+        o = ops.causal_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)))
+        return jnp.einsum("bnsh,nhd->bsd", o, p["wo"].astype(x.dtype))
     # assemble effective q/k with heads as the "KV" axis (G=1) so we can reuse
     # the generic chunked online-softmax
     q_eff = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, S, H, 1, qk_hd)

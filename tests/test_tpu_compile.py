@@ -156,3 +156,34 @@ def test_expert_products_compile_and_carry_stable_names(one_chip, weight):
                     for ln in text.splitlines()
                     if "custom_call_target=\"tpu_custom_call\"" in ln})
     assert calls == ["expert_gmm", "expert_tgmm"], calls
+
+
+# Moonlight-16B-A3B's training attention: batch 2 x 8192 tokens, 16 heads,
+# q/k 192 wide and v 128
+ATTN_SEQ, ATTN_HEADS = 8192, 16
+
+
+def test_causal_attention_compiles_with_its_backward(one_chip, monkeypatch):
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    qk = jax.ShapeDtypeStruct((2, ATTN_HEADS, ATTN_SEQ, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, ATTN_HEADS, ATTN_SEQ, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd_bwd(q, k, v):
+        def f(q, k, v):
+            return jnp.sum(ops.causal_attention(q, k, v).astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    text = _compile_text(fwd_bwd, qk, qk, v)
+    calls = sorted({ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                    for ln in text.splitlines()
+                    if "custom_call_target=\"tpu_custom_call\"" in ln})
+    assert calls == ["splash_mha_dkv_no_residuals",
+                     "splash_mha_dq_no_residuals",
+                     "splash_mha_fwd_residuals"], calls
+    # every kernel's block table is [heads, S / block, S / block] at the
+    # block the code chooses (one head here: the causal masks are equal)
+    n = ATTN_SEQ // ops.attention_block(ATTN_SEQ)
+    tables = {t for t in text.split() if t.startswith("s8[")}
+    assert tables and all(t.startswith(f"s8[1,{n},{n}]") for t in tables), \
+        tables
