@@ -261,5 +261,20 @@ class DeltaTracker:
         which the digest comparison alone cannot flag as a full rewrite)."""
         self._digests.pop(path, None)
 
+    def snapshot(self, paths) -> dict:
+        """The stored digests of ``paths`` (None where a path has none), for
+        :meth:`restore` to put back when a dispatched checkpoint is dropped
+        — the next delta then still diffs against the last STORED one."""
+        return {p: self._digests.get(p) for p in paths}
+
+    def restore(self, snapshot: dict):
+        """Put back the digests a :meth:`snapshot` took (forgetting the
+        paths that had none)."""
+        for p, digest in snapshot.items():
+            if digest is None:
+                self._digests.pop(p, None)
+            else:
+                self._digests[p] = digest
+
     def reset(self):
         self._digests.clear()

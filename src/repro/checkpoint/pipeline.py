@@ -2,11 +2,14 @@
 
 The paper's "lean checkpointing" thesis is that checkpoint cost should track
 what CHANGED, not model size. This layer wires the device-side Pallas
-fingerprint path end-to-end so the record path does, in order:
+fingerprint path end-to-end. One record path serves flat and mesh-sharded
+checkpoints alike; its UNIT is a whole leaf when no mesh is set, or one
+disjoint owner shard of a leaf on a mesh (checkpoint/mesh.py
+``owned_shards``). Per unit the record path does, in order:
 
-1. **Fingerprint + diff on device, fused** — per leaf, `DeltaTracker` runs
-   the fused Pallas fingerprint+changed kernel (one read of the leaf at HBM
-   bandwidth produces BOTH the new digests and the change mask). Digests
+1. **Fingerprint + diff on device, fused** — `DeltaTracker` runs the fused
+   Pallas fingerprint+changed kernel over the unit's own buffer (one read at
+   HBM bandwidth produces BOTH the new digests and the change mask). Digests
    never leave the device; only the [G] change mask and the changed rows do.
 2. **Transfer only changed chunks, wire-format** — exact leaves gather the
    changed u32 block rows; leaves matching the per-slot ``quantize_slots``
@@ -61,14 +64,14 @@ Slots declared via ``error_bounds`` pick, per changed chunk, the cheapest
 encoding whose GUARANTEED bound (delta.Q4_ATOL_DIV / Q8_ATOL_DIV margins)
 satisfies the slot's atol.
 
-Mesh-aware record (``mesh=``): the same flow runs PER DEVICE SHARD — each
-shard's fused fingerprint+gather pass reads only its own buffer, its wire
-chunks land in its host's store shard, and the job writes one v3 member
-manifest per store shard plus a v4 stitching manifest recording the global
-layout (per-leaf shape, recorded PartitionSpec, shard bounds + placement).
-Delta chains run per shard (``<key>.shard<h>``), so inheritance, full-every
-bounds and structure-change fallbacks behave exactly as in the flat path —
-a layout change is a structure change and forces a full manifest. See
+Mesh-aware record (``mesh=``): a unit's bytes only move device -> its
+host's store shard, with no all-gather. The write stage groups the units by
+store shard into one v3 member manifest each (``<key>.shard<h>`` chained to
+``<parent>.shard<h>``, leaves ``<path>::shard<sid>`` with their ``bounds``),
+then a v4 stitching manifest records the global layout (per-leaf shape,
+recorded PartitionSpec, shard bounds + placement). A flat checkpoint is the
+one group, written as one v3 manifest. The placement is part of the
+structure signature, so a layout change forces a full manifest. See
 checkpoint/mesh.py for the restore-side stitch/reshard geometry.
 
 A delta manifest inherits every unlisted chunk hash from its parent chain
@@ -101,6 +104,7 @@ checkpoint/lineage.py for the registry that decides which runs are live).
 from __future__ import annotations
 
 import fnmatch
+import math
 import time
 from typing import Any, Iterable, Optional
 
@@ -147,11 +151,8 @@ class CheckpointPipeline:
         self.full_every = DEFAULT_FULL_EVERY if self.full_every_auto \
             else max(1, int(full_every))
         self.tracker = DeltaTracker(chunk_words)
-        # mesh-aware record: each device shard runs the fused fingerprint
-        # pass over its OWN buffer, its chunks land in its host's store
-        # shard, and a v4 stitching manifest records the layout. shard_axes
-        # picks which mesh axes map onto store shards (default: all — one
-        # store shard per device).
+        # mesh-aware record (units are owned shards): shard_axes picks which
+        # mesh axes map onto store shards (default: all — one per device)
         self.mesh = mesh
         self.shard_axes = tuple(shard_axes or ())
         # true multi-process record: ``dist`` is a
@@ -238,24 +239,25 @@ class CheckpointPipeline:
     # -------------------------------------------------------------- record --
     def submit(self, key: str, tree: Any, meta: Optional[dict] = None,
                scope: str = "default", block: bool = True) -> Optional[dict]:
-        """Fingerprint `tree`, transfer only changed chunks, and enqueue the
-        write stage. Returns submit-side stats (or None when the writer
-        queue is full and block=False — the checkpoint is skipped and the
-        device digest state is rolled back so the next delta stays correct).
-        """
-        if self.mesh is not None:
-            return self._submit_sharded(key, tree, meta, scope, block)
+        """Fingerprint `tree` unit by unit, transfer only changed chunks,
+        and enqueue the write stage. Returns submit-side stats (or None when
+        the writer queue is full and block=False — the checkpoint is skipped
+        and the device digest state is rolled back so the next delta stays
+        correct)."""
         import jax
         t_submit0 = time.perf_counter()
         flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
         prev_sig = self._sig.get(scope, {})
         sig: dict[str, tuple] = {}
-        payload_leaves = []
-        rollback: list[tuple[str, Any]] = []
-        transferred = 0
-        copy_s = 0.0
+        units: list[dict] = []         # one per leaf, or per owned shard
+        layout: list[dict] = []        # mesh only: the v4 manifest's leaves
+        rollback: dict = {}
+        tot = {"transferred_bytes": 0, "changed_chunks": 0, "copy_s": 0.0}
+        # mesh only: per-host foreground cost. Hosts run concurrently in a
+        # real deployment, so the simulated per-checkpoint wall is max over
+        # hosts, not the serial sum this process pays
+        stall: Optional[dict] = None if self.mesh is None else {}
         logical = 0
-        changed_chunks_n = 0
         total_chunks_n = 0
         structure_changed = False
         for path, leaf in flat:
@@ -266,48 +268,72 @@ class CheckpointPipeline:
             shape = list(getattr(leaf, "shape", ()))
             nbytes = _leaf_nbytes(leaf)
             policy = self._slot_policy(pstr, dtype)
-            # the policy is part of the structure signature: flipping a
-            # slot's policy (or changing its error bound) forces a FULL
-            # manifest (and a digest reset), so a chain never inherits
-            # chunks recorded under another encoding without declaring it
-            # per-chunk. Per-chunk choices WITHIN one "eb:" policy do not
-            # force fulls — the manifest's enc/denc fields carry them.
-            sig[pstr] = (dtype, tuple(shape), policy)
+            leaf_units = self._units(leaf, nbytes)
+            # the policy and the placement are part of the structure
+            # signature: flipping a slot's policy (or changing its error
+            # bound) or its layout (resharded mid-run, mesh swap) forces a
+            # FULL manifest and a digest reset, so a chain never inherits
+            # chunks recorded under another encoding or covering other
+            # bytes. Per-chunk choices WITHIN one "eb:" policy do not force
+            # fulls — the manifest's enc/denc fields carry them.
+            sig[pstr] = (dtype, tuple(shape), policy,
+                         tuple((u["sid"], u["hid"],
+                                tuple(map(tuple, u["bounds"])))
+                               for u in leaf_units if u["sid"] is not None))
+            if self.mesh is not None:
+                from repro.checkpoint.mesh import leaf_spec_entries
+                layout.append({"path": pstr, "dtype": dtype, "shape": shape,
+                               "nbytes": nbytes,
+                               "spec": (leaf_spec_entries(leaf) if nbytes
+                                        else None),
+                               "shards": [{"sid": u["sid"], "hid": u["hid"],
+                                           "bounds": u["bounds"]}
+                                          for u in leaf_units]})
             if nbytes == 0:
-                payload_leaves.append({
-                    "path": pstr, "dtype": dtype, "shape": shape,
-                    "nbytes": 0, "n_chunks": 0, "enc": "raw",
-                    "changed_idx": [], "chunks": [], "chunk_encs": []})
+                # nothing to diff: a flat manifest lists the leaf with no
+                # chunks; on a mesh it has no units, only its layout entry
+                units += [{"path": pstr, "sid": None, "hid": None,
+                           "bounds": None, "dtype": dtype, "shape": shape,
+                           "nbytes": 0, "n_chunks": 0, "enc": "raw",
+                           "changed_idx": [], "chunks": [],
+                           "chunk_encs": []} for _ in leaf_units]
                 continue
-            tpath = f"{scope}::{pstr}"
-            old = prev_sig.get(pstr)
-            if old is None or old != sig[pstr]:
+            tpaths = [f"{scope}::{pstr}" + ("" if u["sid"] is None
+                                            else f"::s{u['sid']}")
+                      for u in leaf_units]
+            if prev_sig.get(pstr) != sig[pstr]:
                 structure_changed = True
                 # dtype change with identical block count would otherwise
                 # slip through the digest comparison
-                self.tracker.forget(tpath)
-            rollback.append((tpath, self.tracker._digests.get(tpath)))
-            n_chunks = -(-nbytes // (self.chunk_words
-                                     * native_bytes_per_word(dtype)))
-            lmeta = {"path": pstr, "dtype": dtype, "shape": shape,
-                     "nbytes": nbytes, "n_chunks": n_chunks, "enc": policy}
+                for tpath in tpaths:
+                    self.tracker.forget(tpath)
+            rollback.update(self.tracker.snapshot(tpaths))
             logical += nbytes
-            total_chunks_n += n_chunks
             dkw = self._policy_delta_kwargs(policy)
-            if self.overlap:
-                # dispatch-only: the fused fingerprint+mask launches here;
-                # mask sync, gather and encode run on the writer thread
-                lmeta["handle"] = self.tracker.delta_dispatch(
-                    tpath, _fp_view(leaf), ckpt=key, **dkw)
-            else:
-                d = self.tracker.delta(tpath, _fp_view(leaf), ckpt=key,
-                                       **dkw)
-                t_bytes, n_changed = _attach_changed(d, lmeta,
-                                                     self.chunk_words, key)
-                transferred += t_bytes
-                changed_chunks_n += n_changed
-                copy_s += d["copy_s"]
-            payload_leaves.append(lmeta)
+            for u, tpath in zip(leaf_units, tpaths):
+                local = u["data"]
+                lnb = _leaf_nbytes(local)
+                ent = {"path": pstr, "sid": u["sid"], "hid": u["hid"],
+                       "bounds": u["bounds"], "dtype": dtype,
+                       "shape": list(getattr(local, "shape", ())),
+                       "nbytes": lnb,
+                       "n_chunks": -(-lnb // (self.chunk_words
+                                              * native_bytes_per_word(dtype))),
+                       "enc": policy}
+                total_chunks_n += ent["n_chunks"]
+                t0 = time.perf_counter()
+                h = self.tracker.delta_dispatch(tpath, _fp_view(local),
+                                                ckpt=key, **dkw)
+                if stall is not None:
+                    stall[u["hid"]] = stall.get(u["hid"], 0.0) \
+                        + (time.perf_counter() - t0)
+                if self.overlap:
+                    # dispatch-only: mask sync, gather and encode run on
+                    # the writer thread
+                    ent["handle"] = h
+                else:
+                    self._finalize(ent, h, tot, stall)
+                units.append(ent)
         if set(prev_sig) - set(sig):           # leaf removed
             structure_changed = True
         last = self._last_key.get(scope)
@@ -319,43 +345,77 @@ class CheckpointPipeline:
             "kind": "full" if full else "delta",
             "parent": None if full else last,
             "treedef": str(treedef), "chunk_words": self.chunk_words,
-            "leaves": payload_leaves, "overlap": self.overlap,
-            # overlap mode: transferred/changed are only known once the
-            # writer thread finalizes the deferred gathers (None here; the
-            # materialized stat carries the measured values)
-            "transferred_bytes": None if self.overlap else transferred,
-            "copy_s": None if self.overlap else copy_s,
-            "logical_bytes": logical,
-            "changed_chunks": None if self.overlap else changed_chunks_n,
-            "total_chunks": total_chunks_n,
-            # foreground stall on the training thread (fused fingerprint +
-            # mask sync + changed-row DMA — or dispatch-only in overlap
-            # mode): part of the real M_i — the epsilon overhead invariant
-            # is meaningless if this goes uncounted. It stops before the
-            # wait on the writer's queue, which is the stat's queue_wait_s
-            "submit_stall_s": time.perf_counter() - t_submit0,
+            "units": units, "overlap": self.overlap,
+            "logical_bytes": logical, "total_chunks": total_chunks_n,
         }
-        ok = self._dispatch(payload, block=block)
-        if not ok:
+        # overlap mode: transferred/changed/copy are only known once the
+        # writer thread finalizes the deferred gathers (None here; the
+        # materialized stat carries the measured values)
+        payload.update({k: None if self.overlap else v
+                        for k, v in tot.items()})
+        if self.mesh is not None:
+            payload.update(mesh=self._mesh_meta, layout=layout,
+                           shard_stall_s=stall)
+        # foreground stall on the training thread (fused fingerprint + mask
+        # sync + changed-row DMA — or dispatch-only in overlap mode): part
+        # of the real M_i — the epsilon overhead invariant is meaningless if
+        # this goes uncounted. It stops before the wait on the writer's
+        # queue, which is the stat's queue_wait_s
+        payload["submit_stall_s"] = time.perf_counter() - t_submit0
+        if not self._dispatch(payload, block=block):
             # checkpoint skipped: next delta must still diff against the
             # last STORED checkpoint
-            for tpath, prev in rollback:
-                if prev is None:
-                    self.tracker.forget(tpath)
-                else:
-                    self.tracker._digests[tpath] = prev
+            self.tracker.restore(rollback)
             return None
         self._sig[scope] = sig
         self._last_key[scope] = key
         self._since_full[scope] = 0 if full else since + 1
-        return {"key": key, "kind": payload["kind"],
-                "parent": payload["parent"],
-                "transferred_bytes": payload["transferred_bytes"],
-                "logical_bytes": logical,
-                "changed_chunks": payload["changed_chunks"],
-                "total_chunks": total_chunks_n,
-                "overlap": self.overlap,
-                "submit_stall_s": payload["submit_stall_s"]}
+        if self.dist is not None:
+            self._key_chain.setdefault(scope, []).append(key)
+        out = {k: payload[k] for k in (
+            "key", "kind", "parent", "transferred_bytes", "logical_bytes",
+            "changed_chunks", "total_chunks", "overlap", "submit_stall_s")}
+        if self.mesh is not None:
+            out.update(sharded=True,
+                       n_store_shards=self._mesh_meta["n_store_shards"],
+                       shard_stall_s=dict(stall))
+        return out
+
+    def _units(self, leaf, nbytes: int) -> list[dict]:
+        """The units one leaf records as, each {sid, hid, bounds, data}: the
+        whole leaf when no mesh is set, else its disjoint owner shards (none
+        for a zero-byte leaf)."""
+        if self.mesh is None:
+            return [{"sid": None, "hid": None, "bounds": None, "data": leaf}]
+        if nbytes == 0:
+            return []
+        from repro.checkpoint.mesh import owned_shards
+        return owned_shards(
+            leaf, self._dev_ord, self._dev_host,
+            process_index=(self.dist.group.process_id
+                           if self.dist is not None else None),
+            anchor=self._anchor)
+
+    def _finalize(self, ent: dict, h: dict, tot: dict,
+                  stall: Optional[dict]):
+        """Second half of one unit's fused pass: sync its mask, gather (and
+        quantize) its changed rows, encode them (in a ``flor.ckpt.encode``
+        span) onto ``ent`` as ``changed_idx``, ``chunks`` and
+        ``chunk_encs``, and add the transfer into ``tot`` — and the seconds
+        into ``stall[hid]`` for a mesh unit."""
+        t0 = time.perf_counter()
+        d = self.tracker.finalize(h)
+        with span("flor.ckpt.encode", ckpt=h["ckpt"]):
+            idx, chunks, encs, t_bytes = _encode_changed(d, ent,
+                                                         self.chunk_words)
+        ent["changed_idx"], ent["chunks"], ent["chunk_encs"] = \
+            idx, chunks, encs
+        tot["transferred_bytes"] += t_bytes
+        tot["changed_chunks"] += len(idx)
+        tot["copy_s"] += d["copy_s"]
+        if stall is not None:
+            stall[ent["hid"]] = stall.get(ent["hid"], 0.0) \
+                + (time.perf_counter() - t0)
 
     def _dispatch(self, payload: dict, block: bool) -> bool:
         # the job fills this dict with the write path's counters
@@ -363,13 +423,12 @@ class CheckpointPipeline:
         # as the checkpoint's stat; the submitting thread's queue wait lands
         # in it too, whenever the job runs
         stat = {"queue_wait_s": 0.0}
-        write = self._make_job(payload)
 
         def job(store):
             # the job's chunks go into a few packs, published before the
             # manifests that name them
             with store.counting(stat), store.packing():
-                stat.update(write(store))
+                stat.update(self._write(payload, store))
             return stat
         if self.writer is not None:
             # a blocking put waits here while the writer's queue is full;
@@ -384,121 +443,152 @@ class CheckpointPipeline:
         self._materialized(stat)
         return True
 
-    def _make_job(self, payload: dict):
-        if payload.get("sharded"):
-            return lambda store: self._sharded_job(payload, store)
-
-        def job(store):
-            scope = payload["scope"]
-            if payload.get("overlap"):
-                # deferred half of the fused pass: sync masks, gather (and
-                # quantize) changed rows, encode wire payloads — all off the
-                # training thread
-                transferred = 0
-                changed_n = 0
-                copy_s = 0.0
-                for leaf in payload["leaves"]:
-                    h = leaf.pop("handle", None)
-                    if h is None:              # zero-byte leaf
-                        continue
-                    d = self.tracker.finalize(h)
-                    t_bytes, n_changed = _attach_changed(
-                        d, leaf, payload["chunk_words"], payload["key"])
-                    transferred += t_bytes
-                    changed_n += n_changed
-                    copy_s += d["copy_s"]
-                payload["transferred_bytes"] = transferred
-                payload["changed_chunks"] = changed_n
-                payload["copy_s"] = copy_s
-            entropy_s = sum(self._entropy_pass(leaf)
-                            for leaf in payload["leaves"])
-            hashes_map = self._hashes.setdefault(scope, {})
-            encs_map = self._encs.setdefault(scope, {})
-            full = payload["kind"] == "full"
-            new_bytes = 0
-            new_chunks = 0
-            stored_bytes = 0
-            manifest_leaves = []
-            for leaf in payload["leaves"]:
-                path, n = leaf["path"], leaf["n_chunks"]
-                lenc = leaf.get("enc", "raw")
-                cencs = leaf.get("chunk_encs") \
-                    or ["raw"] * len(leaf["changed_idx"])
-                base = hashes_map.get(path)
-                if base is None or len(base) != n:
-                    base = [None] * n
-                else:
-                    base = list(base)
-                ebase = encs_map.get(path)
-                if ebase is None or len(ebase) != n:
-                    ebase = ["raw"] * n        # pre-v3 state: chunks are raw
-                else:
-                    ebase = list(ebase)
-                delta_hashes = {}
-                for i, data, ce in zip(leaf["changed_idx"], leaf["chunks"],
-                                       cencs):
-                    h, nb, new = store.put_chunk(data)
-                    base[i] = h
-                    ebase[i] = ce
-                    delta_hashes[str(i)] = h
-                    new_bytes += nb
-                    new_chunks += int(new)
-                    stored_bytes += len(data)
-                if any(h is None for h in base):
-                    raise RuntimeError(
-                        f"delta pipeline inconsistency for leaf {path!r}: "
-                        f"unchanged chunks have no known hash (manifest kind "
-                        f"{payload['kind']!r})")
-                hashes_map[path] = base
-                encs_map[path] = ebase
-                mleaf = {"path": path, "dtype": leaf["dtype"],
-                         "shape": leaf["shape"], "nbytes": leaf["nbytes"],
-                         "n_chunks": n}
-                if lenc != "raw":
-                    # leaf-level POLICY (what this pipeline writes), distinct
-                    # from the per-chunk enc lists below: warm_start seeds
-                    # the structure signature from it
-                    mleaf["leaf_enc"] = lenc
-                if full:
-                    mleaf["chunks"] = base
-                    if any(e != "raw" for e in ebase):
-                        mleaf["enc"] = ebase
-                else:
-                    mleaf["delta"] = delta_hashes
-                    denc = {str(i): ce
-                            for i, ce in zip(leaf["changed_idx"], cencs)
-                            if ce != "raw"}
-                    if denc:
-                        mleaf["denc"] = denc
-                manifest_leaves.append(mleaf)
-            if full:    # drop leaves that left the tree
-                current = {lf["path"] for lf in payload["leaves"]}
-                for stale in set(hashes_map) - current:
-                    del hashes_map[stale]
-                    encs_map.pop(stale, None)
+    def _write(self, payload: dict, store) -> dict:
+        """Writer half of a checkpoint: finalize the deferred gathers
+        (overlap mode), run the entropy stage, then write the units' chunks
+        and one v3 manifest per store shard — the whole checkpoint's with no
+        mesh; ``<key>.shard<h>`` members then the v4 stitch on a mesh.
+        Members land BEFORE the stitch, so a crash can leave orphan members
+        but never a v4 that references a missing one."""
+        key, parent = payload["key"], payload["parent"]
+        units = payload["units"]
+        if payload["overlap"]:
+            # deferred half of the fused pass: sync masks, gather (and
+            # quantize) changed rows, encode wire payloads — all off the
+            # training thread
+            tot = {"transferred_bytes": 0, "changed_chunks": 0, "copy_s": 0.0}
+            for u in units:
+                h = u.pop("handle", None)
+                if h is not None:
+                    self._finalize(u, h, tot, payload.get("shard_stall_s"))
+            payload.update(tot)
+        entropy_s = sum(self._entropy_pass(u) for u in units)
+        full = payload["kind"] == "full"
+        if self.mesh is None:
+            groups: dict = {None: units}
+        else:
+            groups = {}
+            for u in units:
+                groups.setdefault(u["hid"], []).append(u)
+        new_bytes = new_chunks = 0
+        members: dict[str, str] = {}
+        shard_write_s: dict[int, float] = {}
+        shard_bytes: dict[int, int] = {}
+        for hid in sorted(groups):
+            t0 = time.perf_counter()
+            leaves, nb, nc, stored = self._write_units(groups[hid], store,
+                                                       hid, payload)
+            new_bytes, new_chunks = new_bytes + nb, new_chunks + nc
+            name, mparent, member = key, parent, {}
+            if hid is not None:     # a member: chained to the parent's
+                name, member = f"{key}.shard{hid}", {"store_shard": hid}
+                mparent = f"{parent}.shard{hid}" if parent else None
             store.put_manifest({
-                "key": payload["key"], "version": 3,
-                "kind": payload["kind"], "parent": payload["parent"],
-                "treedef": payload["treedef"],
-                "chunk_words": payload["chunk_words"],
-                "meta": payload["meta"], "leaves": manifest_leaves,
-            })
+                "key": name, "version": 3, "kind": payload["kind"],
+                "parent": mparent, "treedef": payload["treedef"],
+                "chunk_words": payload["chunk_words"], **member,
+                "meta": payload["meta"] if hid is None else {},
+                "leaves": leaves})
+            members[str(hid)] = name
+            shard_write_s[hid] = time.perf_counter() - t0
+            if stored:
+                shard_bytes[hid] = stored
+        if full:    # drop units that left the tree
+            hashes_map = self._hashes.setdefault(payload["scope"], {})
+            encs_map = self._encs.setdefault(payload["scope"], {})
+            for stale in set(hashes_map) - {_writer_key(u) for u in units}:
+                del hashes_map[stale]
+                encs_map.pop(stale, None)
+        # stitched: True = written (or flat), False = marked incomplete,
+        # None = outcome unknown here (non-lead of a distributed fleet; the
+        # lead decides, close() reconciles the tips from the store)
+        stitched: Optional[bool] = True
+        if self.mesh is not None:
+            if self.dist is None:
+                _put_stitch(store, payload, members, payload["layout"])
+            else:
+                stitched = self._dist_stitch(payload, store, members)
+            if not self._mesh_meta_written and \
+                    (self.dist is None or self.dist.group.is_lead):
+                store.put_meta("mesh", payload["mesh"])
+                self._mesh_meta_written = True
+        if full and stitched:
+            self._retune_full_every(store, payload["logical_bytes"])
+        stat = {k: payload[k] for k in (
+            "key", "kind", "parent", "transferred_bytes", "copy_s",
+            "logical_bytes", "changed_chunks", "total_chunks",
+            "submit_stall_s", "overlap")}
+        stat.update(new_bytes=new_bytes, new_chunks=new_chunks)
+        if self.mesh is None:
+            stat["stored_bytes"] = sum(shard_bytes.values())
+        else:
+            stat.update(sharded=True, stitched=stitched,
+                        n_store_shards=len(groups),
+                        shard_stall_s=dict(payload["shard_stall_s"]),
+                        shard_write_s=shard_write_s, shard_bytes=shard_bytes)
+        stat.update(entropy_s=entropy_s, full_every=self.full_every)
+        return stat
+
+    def _write_units(self, units: list, store, shard,
+                     payload: dict) -> tuple[list, int, int, int]:
+        """Write the units' changed chunks into store shard ``shard``'s pool
+        (the default pool when None), update the writer-side full hash and
+        encoding lists of each unit (keyed by ``_writer_key``), and return
+        (manifest leaves, new_bytes, new_chunks, stored_bytes). A full
+        manifest lists a unit's chunks whole; a delta only the changed
+        ones."""
+        hashes_map = self._hashes.setdefault(payload["scope"], {})
+        encs_map = self._encs.setdefault(payload["scope"], {})
+        full = payload["kind"] == "full"
+        new_bytes = new_chunks = stored_bytes = 0
+        leaves = []
+        for u in units:
+            wkey, n = _writer_key(u), u["n_chunks"]
+            cencs = u.get("chunk_encs") or ["raw"] * len(u["changed_idx"])
+            base = hashes_map.get(wkey)
+            base = [None] * n if base is None or len(base) != n \
+                else list(base)
+            ebase = encs_map.get(wkey)             # pre-v3 state: raw
+            ebase = ["raw"] * n if ebase is None or len(ebase) != n \
+                else list(ebase)
+            delta_hashes = {}
+            for i, data, ce in zip(u["changed_idx"], u["chunks"], cencs):
+                h, nb, new = store.put_chunk(data, shard=shard)
+                base[i] = h
+                ebase[i] = ce
+                delta_hashes[str(i)] = h
+                new_bytes += nb
+                new_chunks += int(new)
+                stored_bytes += len(data)
+            if any(h is None for h in base):
+                raise RuntimeError(
+                    f"delta pipeline inconsistency for {wkey!r}: unchanged "
+                    f"chunks have no known hash (manifest kind "
+                    f"{payload['kind']!r})")
+            hashes_map[wkey] = base
+            encs_map[wkey] = ebase
+            mleaf = {"path": wkey, "dtype": u["dtype"], "shape": u["shape"],
+                     "nbytes": u["nbytes"], "n_chunks": n}
+            if u["sid"] is not None:
+                mleaf["bounds"] = u["bounds"]
+            if u["enc"] != "raw":
+                # leaf-level POLICY (what this pipeline writes), distinct
+                # from the per-chunk enc lists below: warm_start seeds the
+                # structure signature from it
+                mleaf["leaf_enc"] = u["enc"]
             if full:
-                self._retune_full_every(store, payload["logical_bytes"])
-            return {"key": payload["key"], "kind": payload["kind"],
-                    "parent": payload["parent"],
-                    "transferred_bytes": payload["transferred_bytes"],
-                    "copy_s": payload["copy_s"],
-                    "logical_bytes": payload["logical_bytes"],
-                    "changed_chunks": payload["changed_chunks"],
-                    "total_chunks": payload["total_chunks"],
-                    "submit_stall_s": payload["submit_stall_s"],
-                    "overlap": payload.get("overlap", False),
-                    "new_bytes": new_bytes, "new_chunks": new_chunks,
-                    "stored_bytes": stored_bytes,
-                    "entropy_s": entropy_s,
-                    "full_every": self.full_every}
-        return job
+                mleaf["chunks"] = base
+                if any(e != "raw" for e in ebase):
+                    mleaf["enc"] = ebase
+            else:
+                mleaf["delta"] = delta_hashes
+                denc = {str(i): ce
+                        for i, ce in zip(u["changed_idx"], cencs)
+                        if ce != "raw"}
+                if denc:
+                    mleaf["denc"] = denc
+            leaves.append(mleaf)
+        return leaves, new_bytes, new_chunks, stored_bytes
 
     def _entropy_pass(self, leaf: dict) -> float:
         """Writer-thread entropy stage for one leaf: byte-compress its wire
@@ -553,291 +643,6 @@ class CheckpointPipeline:
         self.full_every = min(64, max(2, int(0.5 * full_read_s
                                              / max(hop_s, 1e-9))))
 
-    # ------------------------------------------------------ sharded record --
-    def _submit_sharded(self, key: str, tree: Any, meta: Optional[dict],
-                        scope: str, block: bool) -> Optional[dict]:
-        """Mesh-aware submit: per pytree leaf, enumerate the disjoint owner
-        shards (checkpoint/mesh.py) and run the fused fingerprint+gather
-        pass on EACH shard's own device buffer — no all-gather; a shard's
-        bytes only move device -> its host's store shard. Emits one v3
-        member manifest per store shard plus a v4 stitching manifest."""
-        import jax
-        from repro.checkpoint.mesh import leaf_spec_entries, owned_shards
-        t_submit0 = time.perf_counter()
-        flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-        prev_sig = self._sig.get(scope, {})
-        sig: dict[str, tuple] = {}
-        entries: list[dict] = []       # one per (leaf, device shard)
-        layout: list[dict] = []        # global-manifest leaves
-        rollback: list[tuple[str, Any]] = []
-        transferred = 0
-        copy_s = 0.0
-        logical = 0
-        changed_chunks_n = 0
-        total_chunks_n = 0
-        structure_changed = False
-        shard_stall: dict[int, float] = {}
-        for path, leaf in flat:
-            pstr = jax.tree_util.keystr(path)
-            if not hasattr(leaf, "dtype"):
-                leaf = np.asarray(leaf)
-            dtype = str(leaf.dtype)
-            shape = list(getattr(leaf, "shape", ()))
-            nbytes = _leaf_nbytes(leaf)
-            policy = self._slot_policy(pstr, dtype)
-            if nbytes == 0:
-                sig[pstr] = (dtype, tuple(shape), policy, ())
-                layout.append({"path": pstr, "dtype": dtype, "shape": shape,
-                               "nbytes": 0, "spec": None, "shards": []})
-                continue
-            shards = owned_shards(
-                leaf, self._dev_ord, self._dev_host,
-                process_index=(self.dist.group.process_id
-                               if self.dist is not None else None),
-                anchor=self._anchor)
-            # the placement is part of the structure signature: a layout
-            # change (resharded mid-run, mesh swap) forces a FULL manifest —
-            # per-shard digests from another layout cover different bytes
-            mesh_sig = tuple((s["sid"], s["hid"],
-                              tuple(map(tuple, s["bounds"])))
-                             for s in shards)
-            sig[pstr] = (dtype, tuple(shape), policy, mesh_sig)
-            layout.append({"path": pstr, "dtype": dtype, "shape": shape,
-                           "nbytes": nbytes,
-                           "spec": leaf_spec_entries(leaf),
-                           "shards": [{"sid": s["sid"], "hid": s["hid"],
-                                       "bounds": s["bounds"]}
-                                      for s in shards]})
-            logical += nbytes
-            if prev_sig.get(pstr) != sig[pstr]:
-                structure_changed = True
-                for s in shards:
-                    self.tracker.forget(f"{scope}::{pstr}::s{s['sid']}")
-            for s in shards:
-                tpath = f"{scope}::{pstr}::s{s['sid']}"
-                rollback.append((tpath, self.tracker._digests.get(tpath)))
-                local = s["data"]
-                lnb = _leaf_nbytes(local)
-                n_chunks = -(-lnb // (self.chunk_words
-                                      * native_bytes_per_word(dtype)))
-                ent = {"path": pstr, "sid": s["sid"], "hid": s["hid"],
-                       "bounds": s["bounds"], "dtype": dtype,
-                       "shape": list(getattr(local, "shape", ())),
-                       "nbytes": lnb, "n_chunks": n_chunks, "enc": policy}
-                total_chunks_n += n_chunks
-                dkw = self._policy_delta_kwargs(policy)
-                t0 = time.perf_counter()
-                if self.overlap:
-                    ent["handle"] = self.tracker.delta_dispatch(
-                        tpath, _fp_view(local), ckpt=key, **dkw)
-                else:
-                    d = self.tracker.delta(tpath, _fp_view(local),
-                                           ckpt=key, **dkw)
-                    t_bytes, n_changed = _attach_changed(
-                        d, ent, self.chunk_words, key)
-                    transferred += t_bytes
-                    changed_chunks_n += n_changed
-                    copy_s += d["copy_s"]
-                # per-host foreground cost: hosts run concurrently in a
-                # real deployment, so the simulated per-checkpoint wall is
-                # max over hosts, not the serial sum this process pays
-                shard_stall[s["hid"]] = shard_stall.get(s["hid"], 0.0) \
-                    + (time.perf_counter() - t0)
-                entries.append(ent)
-        if set(prev_sig) - set(sig):
-            structure_changed = True
-        last = self._last_key.get(scope)
-        since = self._since_full.get(scope, 0)
-        full = (last is None or structure_changed
-                or since + 1 >= self.full_every)
-        payload = {
-            "key": key, "scope": scope, "meta": meta or {},
-            "sharded": True, "mesh": self._mesh_meta,
-            "kind": "full" if full else "delta",
-            "parent": None if full else last,
-            "treedef": str(treedef), "chunk_words": self.chunk_words,
-            "entries": entries, "layout": layout, "overlap": self.overlap,
-            "transferred_bytes": None if self.overlap else transferred,
-            "copy_s": None if self.overlap else copy_s,
-            "logical_bytes": logical,
-            "changed_chunks": None if self.overlap else changed_chunks_n,
-            "total_chunks": total_chunks_n,
-            "shard_stall_s": shard_stall,
-            "submit_stall_s": time.perf_counter() - t_submit0,
-        }
-        ok = self._dispatch(payload, block=block)
-        if not ok:
-            for tpath, prev in rollback:
-                if prev is None:
-                    self.tracker.forget(tpath)
-                else:
-                    self.tracker._digests[tpath] = prev
-            return None
-        self._sig[scope] = sig
-        self._last_key[scope] = key
-        self._since_full[scope] = 0 if full else since + 1
-        if self.dist is not None:
-            self._key_chain.setdefault(scope, []).append(key)
-        return {"key": key, "kind": payload["kind"], "sharded": True,
-                "parent": payload["parent"],
-                "transferred_bytes": payload["transferred_bytes"],
-                "logical_bytes": logical,
-                "changed_chunks": payload["changed_chunks"],
-                "total_chunks": total_chunks_n,
-                "overlap": self.overlap,
-                "n_store_shards": self._mesh_meta["n_store_shards"],
-                "shard_stall_s": dict(shard_stall),
-                "submit_stall_s": payload["submit_stall_s"]}
-
-    def _sharded_job(self, payload: dict, store) -> dict:
-        """Writer half of a sharded checkpoint: per store shard, write the
-        changed chunks into that shard's pool and a v3 member manifest
-        (chained ``<key>.shard<h>`` -> ``<parent>.shard<h>``); then the v4
-        stitching manifest. Members land BEFORE the global manifest, so a
-        crash can leave orphan members but never a global that references a
-        missing one."""
-        scope = payload["scope"]
-        if payload.get("overlap"):
-            transferred = 0
-            changed_n = 0
-            copy_s = 0.0
-            for ent in payload["entries"]:
-                h = ent.pop("handle", None)
-                if h is None:
-                    continue
-                t0 = time.perf_counter()
-                d = self.tracker.finalize(h)
-                t_bytes, n_changed = _attach_changed(
-                    d, ent, payload["chunk_words"], payload["key"])
-                transferred += t_bytes
-                changed_n += n_changed
-                copy_s += d["copy_s"]
-                ss = payload["shard_stall_s"]
-                ss[ent["hid"]] = ss.get(ent["hid"], 0.0) \
-                    + (time.perf_counter() - t0)
-            payload["transferred_bytes"] = transferred
-            payload["changed_chunks"] = changed_n
-            payload["copy_s"] = copy_s
-        entropy_s = sum(self._entropy_pass(ent)
-                        for ent in payload["entries"])
-        hashes_map = self._hashes.setdefault(scope, {})
-        encs_map = self._encs.setdefault(scope, {})
-        full = payload["kind"] == "full"
-        key, parent = payload["key"], payload["parent"]
-        by_hid: dict[int, list[dict]] = {}
-        for ent in payload["entries"]:
-            by_hid.setdefault(ent["hid"], []).append(ent)
-        new_bytes = 0
-        new_chunks = 0
-        members: dict[str, str] = {}
-        shard_write_s: dict[int, float] = {}
-        shard_bytes: dict[int, int] = {}
-        for hid in sorted(by_hid):
-            t0 = time.perf_counter()
-            mleaves = []
-            for ent in by_hid[hid]:
-                wkey = f"{ent['path']}::shard{ent['sid']}"
-                n = ent["n_chunks"]
-                lenc = ent["enc"]
-                cencs = ent.get("chunk_encs") \
-                    or ["raw"] * len(ent["changed_idx"])
-                base = hashes_map.get(wkey)
-                base = [None] * n if base is None or len(base) != n \
-                    else list(base)
-                ebase = encs_map.get(wkey)
-                ebase = ["raw"] * n if ebase is None or len(ebase) != n \
-                    else list(ebase)
-                delta_hashes = {}
-                for i, data, ce in zip(ent["changed_idx"], ent["chunks"],
-                                       cencs):
-                    h, nb, new = store.put_chunk(data, shard=hid)
-                    base[i] = h
-                    ebase[i] = ce
-                    delta_hashes[str(i)] = h
-                    new_bytes += nb
-                    new_chunks += int(new)
-                    shard_bytes[hid] = shard_bytes.get(hid, 0) + len(data)
-                if any(h is None for h in base):
-                    raise RuntimeError(
-                        f"sharded delta inconsistency for {wkey!r}: "
-                        f"unchanged chunks have no known hash (manifest "
-                        f"kind {payload['kind']!r})")
-                hashes_map[wkey] = base
-                encs_map[wkey] = ebase
-                mleaf = {"path": wkey, "dtype": ent["dtype"],
-                         "shape": ent["shape"], "nbytes": ent["nbytes"],
-                         "n_chunks": n, "bounds": ent["bounds"]}
-                if lenc != "raw":
-                    mleaf["leaf_enc"] = lenc
-                if full:
-                    mleaf["chunks"] = base
-                    if any(e != "raw" for e in ebase):
-                        mleaf["enc"] = ebase
-                else:
-                    mleaf["delta"] = delta_hashes
-                    denc = {str(i): ce
-                            for i, ce in zip(ent["changed_idx"], cencs)
-                            if ce != "raw"}
-                    if denc:
-                        mleaf["denc"] = denc
-                mleaves.append(mleaf)
-            member_key = f"{key}.shard{hid}"
-            store.put_manifest({
-                "key": member_key, "version": 3,
-                "kind": payload["kind"],
-                "parent": f"{parent}.shard{hid}" if parent else None,
-                "treedef": payload["treedef"],
-                "chunk_words": payload["chunk_words"],
-                "store_shard": hid, "meta": {},
-                "leaves": mleaves,
-            })
-            members[str(hid)] = member_key
-            shard_write_s[hid] = time.perf_counter() - t0
-        if full:
-            current = {f"{ent['path']}::shard{ent['sid']}"
-                       for ent in payload["entries"]}
-            for stale in set(hashes_map) - current:
-                del hashes_map[stale]
-                encs_map.pop(stale, None)
-        # stitched: True = v4 written, False = marked incomplete, None =
-        # outcome unknown here (non-lead of a distributed fleet; the lead
-        # decides, close() reconciles the tips from the store)
-        stitched: Optional[bool] = True
-        if self.dist is None:
-            store.put_manifest({
-                "key": key, "version": 4, "kind": "sharded",
-                "ckpt_kind": payload["kind"], "parent": parent,
-                "treedef": payload["treedef"],
-                "chunk_words": payload["chunk_words"],
-                "mesh": payload["mesh"], "members": members,
-                "meta": payload["meta"], "leaves": payload["layout"],
-            })
-        else:
-            stitched = self._dist_stitch(payload, store, members)
-        if not self._mesh_meta_written and \
-                (self.dist is None or self.dist.group.is_lead):
-            store.put_meta("mesh", payload["mesh"])
-            self._mesh_meta_written = True
-        if full and stitched:
-            self._retune_full_every(store, payload["logical_bytes"])
-        return {"key": key, "kind": payload["kind"], "sharded": True,
-                "stitched": stitched,
-                "parent": parent,
-                "transferred_bytes": payload["transferred_bytes"],
-                "copy_s": payload["copy_s"],
-                "logical_bytes": payload["logical_bytes"],
-                "changed_chunks": payload["changed_chunks"],
-                "total_chunks": payload["total_chunks"],
-                "submit_stall_s": payload["submit_stall_s"],
-                "overlap": payload.get("overlap", False),
-                "new_bytes": new_bytes, "new_chunks": new_chunks,
-                "n_store_shards": len(by_hid),
-                "shard_stall_s": dict(payload["shard_stall_s"]),
-                "shard_write_s": shard_write_s,
-                "shard_bytes": shard_bytes,
-                "entropy_s": entropy_s,
-                "full_every": self.full_every}
-
     # ------------------------------------------------- distributed stitch --
     def _dist_stitch(self, payload: dict, store,
                      members: dict) -> Optional[bool]:
@@ -880,14 +685,7 @@ class CheckpointPipeline:
             self._mark_incomplete(store, key)
             return False
         layout, all_members = merged
-        store.put_manifest({
-            "key": key, "version": 4, "kind": "sharded",
-            "ckpt_kind": payload["kind"], "parent": payload["parent"],
-            "treedef": payload["treedef"],
-            "chunk_words": payload["chunk_words"],
-            "mesh": payload["mesh"], "members": all_members,
-            "meta": payload["meta"], "leaves": layout,
-        })
+        _put_stitch(store, payload, all_members, layout)
         self.dist.clear(key)
         return True
 
@@ -916,16 +714,10 @@ class CheckpointPipeline:
             merged["shards"] = shards
             layout.append(merged)
             if lf["nbytes"] > 0 and lf["shape"]:
-                covered = 0
-                for s in shards:
-                    vol = 1
-                    for lo, hi in s["bounds"]:
-                        vol *= max(0, hi - lo)
-                    covered += vol
-                want = 1
-                for d in lf["shape"]:
-                    want *= int(d)
-                if covered != want:
+                covered = sum(math.prod(max(0, hi - lo)
+                                        for lo, hi in s["bounds"])
+                              for s in shards)
+                if covered != math.prod(int(d) for d in lf["shape"]):
                     return None    # shards don't tile the leaf
         return layout, all_members
 
@@ -992,7 +784,7 @@ class CheckpointPipeline:
             if path not in arrays_by_path:
                 raise ValueError(f"restored tree is missing leaf {path!r}")
             sig[path] = (leaf["dtype"], tuple(leaf["shape"]),
-                         leaf.get("leaf_enc", "raw"))
+                         leaf.get("leaf_enc", "raw"), ())
             hashes[path] = list(chunks)
             encs[path] = list(leaf.get("enc") or ["raw"] * len(chunks))
             nbytes = int(leaf.get("nbytes", 0))
@@ -1043,11 +835,16 @@ class CheckpointPipeline:
                 return
             time.sleep(0.02)
 
+    def tips(self) -> dict[str, str]:
+        """{scope: the last stored checkpoint key of its delta chain}, for
+        every scope that has one."""
+        return {s: k for s, k in self._last_key.items() if k}
+
     def chain_keys(self) -> list[str]:
         """The tip checkpoint key of every scope's delta chain. A GC that
         runs mid-record MUST keep these live (their parent closure carries
         every chunk hash the next delta manifest will inherit)."""
-        return [k for k in self._last_key.values() if k]
+        return list(self.tips().values())
 
     def reset(self):
         """Forget all digest / chain state (next submits are full)."""
@@ -1061,19 +858,6 @@ class CheckpointPipeline:
     @property
     def stats(self) -> list[dict]:
         return list(self._stats)
-
-
-def _attach_changed(d: dict, lmeta: dict, chunk_words: int,
-                    ckpt: str) -> tuple[int, int]:
-    """Encode one finalized delta's changed rows inside a
-    ``flor.ckpt.encode`` span and set them on the leaf entry as
-    ``changed_idx``, ``chunks`` and ``chunk_encs``. Returns
-    (transferred_bytes, changed chunk count)."""
-    with span("flor.ckpt.encode", ckpt=ckpt):
-        idx, chunks, encs, t_bytes = _encode_changed(d, lmeta, chunk_words)
-    lmeta["changed_idx"], lmeta["chunks"], lmeta["chunk_encs"] = \
-        idx, chunks, encs
-    return t_bytes, len(idx)
 
 
 def _encode_changed(d: dict, lmeta: dict, chunk_words: int):
@@ -1097,20 +881,14 @@ def _encode_changed(d: dict, lmeta: dict, chunk_words: int):
     out: dict[int, tuple[str, bytes]] = {}
     for gr in d["enc_groups"]:
         e = gr["enc"]
-        if e == "q8":
-            block = min(Q8_BLOCK, chunk_words)
+        if e in ("q8", "q4"):
+            rows, encode, block = (gr["q"], q8_encode_chunk, Q8_BLOCK) \
+                if e == "q8" else (gr["packed"], q4_encode_chunk, Q4_BLOCK)
             for j, i in enumerate(gr["idx"].tolist()):
                 n_el = chunk_words if i < n_chunks - 1 \
                     else total_elems - (n_chunks - 1) * chunk_words
-                out[int(i)] = ("q8", q8_encode_chunk(
-                    gr["q"][j], gr["scales"][j], n_el, block))
-        elif e == "q4":
-            block = min(Q4_BLOCK, chunk_words)
-            for j, i in enumerate(gr["idx"].tolist()):
-                n_el = chunk_words if i < n_chunks - 1 \
-                    else total_elems - (n_chunks - 1) * chunk_words
-                out[int(i)] = ("q4", q4_encode_chunk(
-                    gr["packed"][j], gr["scales"][j], n_el, block))
+                out[int(i)] = (e, encode(rows[j], gr["scales"][j], n_el,
+                                         min(block, chunk_words)))
         else:
             native = blocks_to_native_bytes(gr["blocks"], dtype)
             # tracker clamps changed_idx to the leaf's real chunk count, so
@@ -1132,6 +910,13 @@ def _match_slot(pstr: str, pat: str) -> bool:
             or f".{pat}" in pstr or fnmatch.fnmatch(pstr, pat))
 
 
+def _writer_key(unit: dict) -> str:
+    """The writer-side key of one unit's hash and encoding lists, and its
+    manifest leaf's path: the leaf path, ``::shard<sid>`` for a shard."""
+    return unit["path"] if unit["sid"] is None \
+        else f"{unit['path']}::shard{unit['sid']}"
+
+
 def _fp_view(leaf):
     """The array the fingerprint actually runs over. 64-bit HOST leaves get
     a bit-preserving u32 view: jit would silently downcast them when jax x64
@@ -1144,7 +929,17 @@ def _fp_view(leaf):
 
 
 def _leaf_nbytes(leaf) -> int:
-    if hasattr(leaf, "nbytes"):
-        return int(leaf.nbytes)
-    a = np.asarray(leaf)
-    return int(a.nbytes)
+    return int(leaf.nbytes if hasattr(leaf, "nbytes")
+               else np.asarray(leaf).nbytes)
+
+
+def _put_stitch(store, payload: dict, members: dict, layout: list):
+    """Write a mesh checkpoint's v4 stitching manifest: the global layout
+    and the member manifest of every store shard."""
+    store.put_manifest({
+        "key": payload["key"], "version": 4, "kind": "sharded",
+        "ckpt_kind": payload["kind"], "parent": payload["parent"],
+        "treedef": payload["treedef"], "chunk_words": payload["chunk_words"],
+        "mesh": payload["mesh"], "members": members,
+        "meta": payload["meta"], "leaves": layout,
+    })

@@ -595,7 +595,7 @@ class FlorContext:
             pipeline, self.pipeline = self.pipeline, None
             pipeline.close()
             self.writer = None
-            final_keys = {s: k for s, k in pipeline._last_key.items() if k}
+            final_keys = pipeline.tips()
         if self.rendezvous is not None:
             # all stitches are settled (pipeline closed above): stop the
             # liveness beater so a dead-on-exit process cannot look alive

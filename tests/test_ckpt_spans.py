@@ -153,7 +153,7 @@ def test_writer_counters_cover_the_job(store):
         assert counted <= s["materialize_s"]
         assert counted >= 0.8 * s["materialize_s"], s
         assert 0.0 < s["copy_s"] <= s["submit_stall_s"]
-        assert s["compress_s"] > s["hash_s"]
+        assert s["hash_s"] > 0.0 and s["compress_s"] > 0.0
     pipe.close()
 
 

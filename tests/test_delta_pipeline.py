@@ -363,11 +363,38 @@ def test_queue_full_rolls_back_digests(tmp_path):
     skipped = pipe.submit("b", dict(t, head=t["head"] + 1), scope="s")
     assert skipped is None
     pipe.writer = real_writer
-    s = pipe.submit("c", dict(t, head=t["head"] + 2), scope="s")
+    # the same state the skipped submit saw: only a rolled-back digest
+    # still sees its head as changed
+    s = pipe.submit("c", dict(t, head=t["head"] + 1), scope="s")
     pipe.close()
     # head changed relative to "a" — must be transferred even though the
     # intermediate submit saw (and dropped) a newer digest
     assert s["changed_chunks"] >= 1
     back = store.get_tree("c", like=t)
     np.testing.assert_array_equal(np.asarray(back["head"]),
-                                  np.asarray(t["head"]) + 2)
+                                  np.asarray(t["head"]) + 1)
+
+
+def test_tips_name_the_last_stored_key_after_a_refused_submit(tmp_path):
+    """A checkpoint refused by a full queue (block=False) does not move its
+    scope's tip: tips() and chain_keys() still name the last STORED key of
+    each scope."""
+    store = CheckpointStore(str(tmp_path / "s"))
+    pipe = CheckpointPipeline(store, chunk_words=256, async_stage=False)
+    t = _tree(1.0)
+    assert pipe.tips() == {}
+    pipe.submit("a0", t, scope="a")
+    pipe.submit("b0", t, scope="b")
+    pipe.submit("a1", dict(t, head=t["head"] + 1), scope="a")
+
+    class _Full:
+        def submit_job(self, key, fn, block=True):
+            return False
+    real_writer, pipe.writer = pipe.writer, _Full()
+    assert pipe.submit("a2", dict(t, head=t["head"] + 2), scope="a",
+                       block=False) is None
+    pipe.writer = real_writer
+    assert pipe.tips() == {"a": "a1", "b": "b0"}
+    assert sorted(pipe.chain_keys()) == ["a1", "b0"]
+    assert all(store.has(k) for k in pipe.tips().values())
+    pipe.close()
